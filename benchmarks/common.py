@@ -1,8 +1,9 @@
 """Shared benchmark helpers: fast-mode defaults, timing, CSV + JSON emission.
 
-Also enables JAX's persistent compilation cache (results/.jax_cache) so
-repeated benchmark runs — and the separate suites of one run — skip
-recompiling the sweep-engine programs.
+Also enables JAX's persistent compilation cache
+(`repro.compile_cache`: ``$JAX_COMPILATION_CACHE_DIR`` if set, else
+results/.jax_cache) so repeated benchmark runs — and the separate
+suites of one run — skip recompiling the sweep-engine programs.
 """
 
 from __future__ import annotations
@@ -12,6 +13,8 @@ import os
 import tempfile
 import time
 
+from repro.compile_cache import enable_compilation_cache
+
 FAST = os.environ.get("BENCH_FAST", "1") != "0"
 
 _RESULTS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -19,36 +22,25 @@ _RESULTS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 SWEEP_JSON = os.environ.get(
     "BENCH_SWEEP_JSON", os.path.join(_RESULTS_DIR, "BENCH_sweep.json"))
 
-
-def _enable_compilation_cache() -> None:
-    try:
-        import jax
-        cache_dir = os.path.join(_RESULTS_DIR, ".jax_cache")
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:       # pragma: no cover — cache is a pure optimization
-        pass
+enable_compilation_cache()
 
 
-_enable_compilation_cache()
-
-
-def fast_params():
+def fast_params(fast: bool = FAST):
     """(n_traces, horizon_s, n_apps_per_bucket) for fast vs full runs."""
-    return (3, 1800, 4) if FAST else (10, 7200, None)
+    return (3, 1800, 4) if fast else (10, 7200, None)
 
 
 def backend_meta() -> dict:
-    """{backend, n_devices} for the sweep execution backend this run
-    resolves to (``BENCH_SWEEP_BACKEND``), recorded with every suite
-    entry so BENCH_sweep.json numbers are attributable to a backend."""
-    try:
-        from repro.sim.exec import get_backend
-        b = get_backend()
-        return {"backend": b.name, "n_devices": b.n_devices}
-    except Exception:   # pragma: no cover — meta only, never break a bench
-        return {}
+    """{backend, n_devices, platform} for the sweep execution backend
+    this run resolves to (``BENCH_SWEEP_BACKEND``), recorded with every
+    suite entry so BENCH_sweep.json numbers are attributable to a
+    backend and a device."""
+    import jax
+
+    from repro.sim.exec import get_backend
+    b = get_backend()
+    return {"backend": b.name, "n_devices": b.n_devices,
+            "platform": jax.devices()[0].platform}
 
 
 def emit(name: str, rows: list[dict], t0: float) -> None:

@@ -15,7 +15,7 @@ The DP runs on the structured O(N log N) min-plus transition (the
 `transition="structured"` backend; monotone segment decomposition, see
 core.dp), which removed this suite's O(N^2)-per-interval compute wall:
 ~56s -> well under the 30s CI ceiling in fast mode. Set
-BENCH_TRANSITION=dense (or kernel) to benchmark the other backends.
+BENCH_TRANSITION=dense to benchmark the dense O(N^2) oracle transition.
 """
 
 from __future__ import annotations

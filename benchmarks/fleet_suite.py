@@ -28,13 +28,16 @@ scale with cores, so wall times are only comparable at equal
 ``host_cpu_count``.
 
 Fast mode: 60 s tenant horizons; full: 180 s at doubled per-tenant
-demand.
+demand on a worker table widened to 64 FPGA + 128 CPU slots (the
+default 32 + 64 drops dispatches at 1024 tenants). Every cell must
+report ``slot_overflow == 0``.
 """
 
 from __future__ import annotations
 
 import os
 import sys
+from typing import NamedTuple
 
 # allow `python benchmarks/fleet_suite.py` from anywhere
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -61,22 +64,43 @@ SCALES = (16, 64, 256, 1024)
 MAX_SWEEP_DISPATCHES = 8
 
 
-def run() -> list[dict]:
-    horizon_s = 60.0 if FAST else 180.0
-    demand = 0.05 if FAST else 0.1
+class FleetGrid(NamedTuple):
+    cells: list
+    horizon_s: float
+    demand: float            # mean workers of demand per tenant
+    w_fpga: int              # worker-table slots the grid needs
+    w_cpu: int
 
+
+def grid(fast: bool = FAST) -> FleetGrid:
+    """Every population size under every admission policy. The worker
+    table is sized to the grid's demand: full mode offers ~102 workers
+    at 1024 tenants, which overflows the default 32 + 64 slots (dropped
+    dispatches, ``slot_overflow > 0``), so it gets 64 + 128."""
+    horizon_s = 60.0 if fast else 180.0
+    demand = 0.05 if fast else 0.1
     pops = {n: tenant_population(n, horizon_s=horizon_s,
                                  mean_demand_workers=demand, seed=1)
             for n in SCALES}
     cells = [FleetCell(tenants=pops[n], admission=adm, tag=(n, adm))
              for n in SCALES for adm in admission_policy_names()]
+    w_fpga, w_cpu = (32, 64) if fast else (64, 128)
+    return FleetGrid(cells, horizon_s, demand, w_fpga, w_cpu)
 
-    res = sweep_fleet(cells)
+
+def run() -> list[dict]:
+    g = grid()
+    cells, horizon_s, demand = g.cells, g.horizon_s, g.demand
+    res = sweep_fleet(cells, w_fpga=g.w_fpga, w_cpu=g.w_cpu)
     assert res.n_dispatches <= MAX_SWEEP_DISPATCHES, (
         f"fleet grid took {res.n_dispatches} sweep dispatches "
         f"(> {MAX_SWEEP_DISPATCHES}) — did the admission policy leak "
         f"into a static group key?")
     check_fleet_result(res, where="fleet_suite")
+    for i in range(len(res)):
+        over = res.totals(i).breakdown["slot_overflow"]
+        assert over == 0, f"fleet cell {res.cells[i].tag}: {over} " \
+                          f"dispatches dropped (slot_overflow)"
 
     rows = []
     for i, cell in enumerate(res.cells):
@@ -106,6 +130,7 @@ def run() -> list[dict]:
     record_kv("fleet_suite_meta",
               scales=list(SCALES), admission=list(admission_policy_names()),
               horizon_s=horizon_s, mean_demand_workers=demand,
+              worker_table=[g.w_fpga, g.w_cpu],
               sweep_dispatches=res.n_dispatches, sweep_cells=len(res),
               conservation_checked=True, fast=FAST,
               host_cpu_count=os.cpu_count(),

@@ -6,18 +6,18 @@ axis over a 1-D device mesh. Until this suite, it had only ever been
 closes the ROADMAP carried-context item by timing real event/fleet
 sweep grids at ``--xla_force_host_platform_device_count=8``:
 
-  * a subprocess fabricates 8 host CpuDevices (XLA splits the host CPU;
-    the devices time-share the physical cores, so on a small container
-    these rows measure sharding *overhead*, not parallel speedup — the
-    per-row ``host_cpu_count`` is what makes the numbers interpretable);
+  * a ``JAX_PLATFORMS=cpu`` subprocess fabricates 8 host CpuDevices
+    (XLA splits the host CPU; the devices time-share the physical
+    cores, so on a small container these rows measure sharding
+    *overhead*, not parallel speedup — the per-row ``host_cpu_count`` is
+    what makes the numbers interpretable). It never touches an
+    accelerator the parent holds, and a child that fails fails the
+    suite;
   * the parent process times the identical grids on the 1-device local
-    backend for the baseline rows;
-  * both arrival backends (``xla`` | ``pallas`` — the fused
-    `repro.kernels.arrival` kernel) are timed on the mesh, so the
-    kernel path's mesh interaction is on record too.
+    backend for the baseline rows, on whatever platform it runs.
 
-Every row records ``{suite, backend, n_devices, arrival_backend,
-wall_s}``; the merged record lands in results/BENCH_sweep.json under
+Every row records ``{suite, backend, platform, n_devices, wall_s}``;
+the merged record lands in results/BENCH_sweep.json under
 ``mesh_manycore``.
 """
 
@@ -83,37 +83,34 @@ def _timeit(fn) -> float:
     return time.time() - t0
 
 
-def _measure(backend: str | None, n_devices: int,
-             arrival_backends=("xla",)) -> list[dict]:
+def _measure(backend: str | None, n_devices: int) -> list[dict]:
+    import jax
+
     from repro.sim.sweep import sweep_events, sweep_fleet
 
     ev, fl = _event_cells(), _fleet_cells()
-    rows = []
-    for ab in arrival_backends:
-        w = _timeit(lambda: sweep_events(ev, n_max=128, backend=backend,
-                                         arrival_backend=ab))
-        rows.append({"suite": "events", "backend": backend or "local",
-                     "n_devices": n_devices, "arrival_backend": ab,
-                     "cells": len(ev), "wall_s": round(w, 3)})
-        w = _timeit(lambda: sweep_fleet(fl, backend=backend,
-                                        arrival_backend=ab))
-        rows.append({"suite": "fleet", "backend": backend or "local",
-                     "n_devices": n_devices, "arrival_backend": ab,
-                     "cells": len(fl), "wall_s": round(w, 3)})
-    return rows
+    platform = jax.devices()[0].platform
+    w_ev = _timeit(lambda: sweep_events(ev, n_max=128, backend=backend))
+    w_fl = _timeit(lambda: sweep_fleet(fl, backend=backend))
+    return [{"suite": suite, "backend": backend or "local",
+             "platform": platform, "n_devices": n_devices,
+             "cells": n, "wall_s": round(w, 3)}
+            for suite, n, w in (("events", len(ev), w_ev),
+                                ("fleet", len(fl), w_fl))]
 
 
 def _probe() -> None:
-    """Subprocess entry: run under the fabricated-device XLA flag."""
+    """Subprocess entry: run under ``JAX_PLATFORMS=cpu`` and the
+    fabricated-device XLA flag."""
     import jax
-    n_dev = jax.device_count()
-    rows = _measure("mesh", n_dev, arrival_backends=("xla", "pallas"))
+    rows = _measure("mesh", jax.device_count())
     print(_PROBE_MARK + json.dumps(rows), flush=True)
 
 
 def run() -> list[dict]:
     rows = _measure(None, 1)
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                          f" --xla_force_host_platform_device_count="
                          f"{FABRICATED_DEVICES}").strip(),
@@ -127,14 +124,14 @@ def run() -> list[dict]:
             rows += json.loads(line[len(_PROBE_MARK):])
             break
     else:
-        print(f"many-core probe failed (rc={proc.returncode}):\n"
-              f"{proc.stderr[-2000:]}", file=sys.stderr)
+        raise RuntimeError(f"many-core probe failed (rc={proc.returncode}):"
+                           f"\n{proc.stderr[-2000:]}")
     record_kv("mesh_manycore", rows=rows, fast=FAST,
               host_cpu_count=os.cpu_count(),
               fabricated_devices=FABRICATED_DEVICES)
     for r in rows:
         print(f"{r['suite']:7s} backend={r['backend']:6s} "
-              f"dev={r['n_devices']} arrival={r['arrival_backend']:6s} "
+              f"{r['platform']} dev={r['n_devices']} "
               f"cells={r['cells']:3d} wall={r['wall_s']:.1f}s")
     return rows
 

@@ -11,7 +11,8 @@ Methodology (EXPERIMENTS.md §Roofline):
   * memory-fit numbers come from the full-depth compile (scan form, the
     deployable artifact).
 
-Terms per cell (v5e chip constants in launch.mesh):
+Terms per cell (peaks of the target chip, `launch.mesh.chip_peaks` of
+``TARGET_DEVICE_KIND`` — TPU v5e):
     compute_s    = HLO_FLOPs_dev / 197e12
     memory_s     = HLO_bytes_dev / 819e9
     collective_s = collective_bytes_dev / 50e9   (per-link ICI)
@@ -21,7 +22,12 @@ the usefulness ratio MODEL_FLOPS / HLO_FLOPs.
 Also includes the min-plus DP transition scaling study (``kind:
 "minplus"`` rows): dense O(N^2) vs structured O(N log N) wall time per
 step at N in {128, 512, 2048, 8192}, so the asymptotic win behind the
-fig2 speedup is visible in results/roofline.json.
+fig2 speedup is visible in results/roofline.json. Each row names the
+platform and device kind it was timed on.
+
+``--collect`` runs each dry-run lowering in a ``JAX_PLATFORMS=cpu``
+child (placeholder CPU devices; it never contends for an accelerator),
+and a child that fails fails the collection.
 
 Usage:
     python -m benchmarks.roofline --collect   # runs the reduced lowerings
@@ -32,13 +38,14 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
 from pathlib import Path
 
 from repro.configs.registry import SHAPES, cells, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_DEVICE_KIND, chip_peaks
 
 DRYRUN_DIR = Path("results/dryrun")
 OUT = Path("results/roofline.json")
@@ -69,8 +76,8 @@ def collect(only: list[str] | None = None) -> None:
                    "--layers", str(L), "--unroll",
                    "--out", str(DRYRUN_DIR)]
             print("collect:", " ".join(cmd[3:]))
-            subprocess.run(cmd, env={**__import__("os").environ,
-                                     "PYTHONPATH": "src"}, check=False)
+            subprocess.run(cmd, env={**os.environ, "PYTHONPATH": "src",
+                                     "JAX_PLATFORMS": "cpu"}, check=True)
 
 
 def model_flops(arch: str, shape: str) -> float:
@@ -118,9 +125,10 @@ def extrapolate(arch: str, shape: str) -> dict | None:
         "mem_dev_bytes": (full or b).get("device_bytes_total", 0),
         "compile_ok_full": bool(full),
     }
-    rec["compute_s"] = rec["flops_dev"] / PEAK_FLOPS_BF16
-    rec["memory_s"] = rec["bytes_dev"] / HBM_BW
-    rec["collective_s"] = rec["coll_bytes_dev"] / ICI_BW_PER_LINK
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
+    rec["compute_s"] = rec["flops_dev"] / peaks.flops_bf16
+    rec["memory_s"] = rec["bytes_dev"] / peaks.hbm_bw
+    rec["collective_s"] = rec["coll_bytes_dev"] / peaks.ici_bw_per_link
     terms = {"compute": rec["compute_s"], "memory": rec["memory_s"],
              "collective": rec["collective_s"]}
     rec["bottleneck"] = max(terms, key=terms.get)
@@ -131,7 +139,7 @@ def extrapolate(arch: str, shape: str) -> dict | None:
                            if rec["flops_dev"] > 0 else 0.0)
     # roofline fraction: useful work per second at the bottleneck
     step_s = max(terms.values())
-    ideal_s = rec["model_flops_dev"] / PEAK_FLOPS_BF16
+    ideal_s = rec["model_flops_dev"] / peaks.flops_bf16
     rec["roofline_fraction"] = ideal_s / step_s if step_s > 0 else 0.0
     return rec
 
@@ -140,31 +148,24 @@ MINPLUS_NS = (128, 512, 2048, 8192)
 
 
 def minplus_scaling(ns=MINPLUS_NS, reps: int = 3) -> list[dict]:
-    """Dense vs structured vs Pallas-kernel min-plus transition wall
-    time per step.
+    """Dense vs structured min-plus transition wall time per step.
 
     One jitted step per (backend, N), timed post-compile (best of
     ``reps``), on a random monotone y_c instance — the same contraction
     the DP runs T times per solve, so the dense/structured ratio here is
-    the per-interval speedup behind fig2. The "kernel" backend is the
-    structured Pallas kernel in whatever execution mode
-    `repro.kernels.backend.pallas_mode` probes on this host; the mode
-    rides in every row (``pallas_mode``) so an interpret-mode number is
-    never mistaken for a compiled-kernel one."""
+    the per-interval speedup behind fig2. Every row names the platform
+    and device kind it ran on."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core.dp import minplus_step_jnp, minplus_step_structured
-    from repro.kernels.backend import pallas_mode
-    from repro.kernels.minplus.ops import minplus_step_structured as _k
 
     backends = {"dense": jax.jit(minplus_step_jnp),
                 "structured": jax.jit(
                     lambda F, p, c, co: minplus_step_structured(
-                        F, p, c, co, check=False)),
-                "kernel": jax.jit(_k)}
-    mode = pallas_mode()
+                        F, p, c, co, check=False))}
+    dev = jax.devices()[0]
     rows = []
     for n in ns:
         rng = np.random.default_rng(n)
@@ -172,7 +173,8 @@ def minplus_scaling(ns=MINPLUS_NS, reps: int = 3) -> list[dict]:
         ycp = jnp.asarray(np.sort(rng.integers(0, n, n))[::-1], jnp.float32)
         ycc = jnp.asarray(np.sort(rng.integers(0, n, n))[::-1], jnp.float32)
         coeffs = (500.0, 5.0, 0.75, 0.75)
-        row = {"kind": "minplus", "n": n, "pallas_mode": mode}
+        row = {"kind": "minplus", "n": n, "platform": dev.platform,
+               "device_kind": dev.device_kind}
         for name, fn in backends.items():
             out, arg = fn(F, ycp, ycc, coeffs)          # compile + warm
             out.block_until_ready()

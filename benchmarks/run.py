@@ -3,12 +3,15 @@ summary. Prints ``name,us_per_call,derived`` CSV lines and records
 per-suite wall time in results/BENCH_sweep.json.
 
 BENCH_FAST=0 for full-size runs (10 traces, 2h horizons, all apps).
+A suite that raises is reported as ``name,0,error=...`` and the
+remaining suites still run; the exit code is then 1.
 """
 
 from __future__ import annotations
 
 import os
 import sys
+import traceback
 
 # `python benchmarks/run.py` puts benchmarks/ (not the repo root) on
 # sys.path; make the repo root and src/ importable regardless of cwd.
@@ -18,7 +21,7 @@ for p in (_ROOT, os.path.join(_ROOT, "src")):
         sys.path.insert(0, p)
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import (chaos_suite, fig2_pareto, fig4_spork_vs_mark,
                             fig5_sensitivity, fig6_worker_efficiency,
                             fig7_request_sizes, fleet_suite, policy_tuning,
@@ -49,14 +52,18 @@ def main() -> None:
         ("policy_tuning", policy_tuning.run),
         ("roofline", roofline.run),
     ]
+    failed = []
     for name, fn in suites:
         try:
             rows, t0 = timed(fn)
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — report, run the rest
+            traceback.print_exc()
             print(f"{name},0,error={type(e).__name__}:{e}")
+            failed.append(name)
             continue
         emit(name, rows, t0)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

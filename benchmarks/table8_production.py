@@ -17,7 +17,7 @@ from repro.core.traces import production_like_apps
 from repro.core.workers import DEFAULT_FLEET
 from repro.sim.sweep import SweepCell, sweep, tune_fpga_dynamic_cells
 
-from benchmarks.common import fast_params
+from benchmarks.common import FAST, fast_params
 
 SCHEDULERS = [
     ("CPU-dynamic", "cpu_dynamic", {}),
@@ -31,8 +31,12 @@ SCHEDULERS = [
 ]
 
 
-def run(buckets=("short", "medium"), sources=("azure", "alibaba")) -> list[dict]:
-    _, horizon, n_apps = fast_params()
+def grid(fast: bool = FAST, buckets=("short", "medium"),
+         sources=("azure", "alibaba")):
+    """(plain cells, tuned FPGA-dynamic cells, row order) of the Table-8
+    grid: every (source, bucket, scheduler, app) cell. Full mode
+    (``fast=False``) runs every Table-7 app over a 7200 s horizon."""
+    _, horizon, n_apps = fast_params(fast)
     fleet = DEFAULT_FLEET
 
     # App trace batches up front, one set per (source, bucket).
@@ -54,6 +58,12 @@ def run(buckets=("short", "medium"), sources=("azure", "alibaba")) -> list[dict]
                                  energy_weight=kw.get("energy_weight", 1.0),
                                  tag=(source, bucket, label))
                 (tuned if kw.get("tuned") else plain).append(cell)
+    return plain, tuned, order
+
+
+def run(buckets=("short", "medium"), sources=("azure", "alibaba")) -> list[dict]:
+    fleet = DEFAULT_FLEET
+    plain, tuned, order = grid(FAST, buckets, sources)
 
     merged: dict[tuple, RunTotals] = {}
 

@@ -14,25 +14,15 @@ Two interchangeable engines (``engine=`` / ``BENCH_TABLE9_ENGINE``):
                   oracle exactly on integer-quantized traces and to ~1%
                   on these continuous ones (docs/architecture.md).
 
-``batched`` is the fast-mode default: measured 1.1-1.7x vs the serial
-oracle on this grid even on a 1-core CPU host (three separate runs —
-rows in results/BENCH_sweep.json under ``table9_engine_compare``; the
-oracle's per-request Python/heapq cost now exceeds the vectorized
-engine's XLA per-primitive tax at this grid size). The flip is
-measurement-gated: re-run ``--compare`` on a new host and set
-``BENCH_TABLE9_ENGINE=python`` where serial wins there.
-
-The batched engine additionally takes ``arrival_backend=("xla"|"pallas")``
-(env: ``BENCH_ARRIVAL_BACKEND``): "pallas" routes every arrival block
-through the fused `repro.kernels.arrival` kernel. Run ``python
-benchmarks/table9_dispatch.py --compare`` to re-measure all engine x
-arrival-backend combinations on this host AND on a fabricated many-core
-host (``--xla_force_host_platform_device_count=8`` + the mesh exec
-backend, in a subprocess) and refresh the record: per-row
-``{engine, arrival_backend, backend, n_devices, wall_s,
-speedup_vs_python}`` plus an honesty ``analysis`` field. The fast-mode
-default only flips to the batched engine where a recorded row measures
->1x vs serial.
+``batched`` is the fast-mode default. It rests on CPU rows only
+(results/BENCH_sweep.json ``table9_engine_compare``: 1.1-1.7x vs the
+serial oracle on a 1-core CPU host); no chip measurement has decided it
+yet. Run ``python benchmarks/table9_dispatch.py --compare`` to
+re-measure both engines in this process and on a fabricated 8-device
+CPU mesh (``--xla_force_host_platform_device_count=8`` with
+``JAX_PLATFORMS=cpu``, in a subprocess) and refresh the record: per-row
+``{engine, backend, platform, n_devices, wall_s, speedup_vs_python}``
+plus an ``analysis`` field.
 """
 
 from __future__ import annotations
@@ -65,21 +55,18 @@ CASES = [("azure-like(short)", 0.68, 0.05),
 
 DISPATCHERS = ("round_robin", "index_packing", "spork")
 
-#: Fast-mode engine default — flipped to "batched" by the measured
-#: >1x rows in results/BENCH_sweep.json ``table9_engine_compare``
-#: (1.09x/1.41x/1.72x vs serial across three runs, xla arrival path,
-#: local backend). The pallas arrival path did NOT beat serial here
-#: (interpret mode on CPU), so BENCH_ARRIVAL_BACKEND keeps its "xla"
-#: default separately.
+#: Fast-mode engine default, set from the CPU rows in
+#: results/BENCH_sweep.json ``table9_engine_compare`` (1.09x/1.41x/1.72x
+#: vs serial across three 1-core CPU runs, local backend).
 DEFAULT_ENGINE = "batched"
 
 
-def _grid():
+def _grid(fast: bool = FAST):
     """(label, [(arrival_times, size_s), ...]) per case; traces are
     dispatch-policy-independent so they are generated once per (case,
     app) and shared across all three policies and both engines."""
-    horizon = 900 if FAST else 3600
-    n_apps = 2 if FAST else 5
+    horizon = 900 if fast else 3600
+    n_apps = 2 if fast else 5
     grid = []
     for label, bias, size in CASES:
         apps = []
@@ -92,7 +79,7 @@ def _grid():
     return grid, horizon
 
 
-def run(engine: str | None = None, arrival_backend: str | None = None,
+def run(engine: str | None = None,
         backend: str | None = None) -> list[dict]:
     engine = engine or os.environ.get("BENCH_TABLE9_ENGINE", DEFAULT_ENGINE)
     assert engine in ("python", "batched"), engine
@@ -106,8 +93,7 @@ def run(engine: str | None = None, arrival_backend: str | None = None,
                  for label, apps in grid
                  for disp in DISPATCHERS
                  for arr, size_s in apps]
-        totals = sweep_events(cells, n_max=N_MAX, backend=backend,
-                              arrival_backend=arrival_backend).totals()
+        totals = sweep_events(cells, n_max=N_MAX, backend=backend).totals()
         for cell, tot in zip(cells, totals):
             assert tot.breakdown.get("slot_overflow", 0) == 0
             prev = merged.get(cell.tag)
@@ -153,44 +139,45 @@ def _timeit(fn) -> float:
     return time.time() - t0
 
 
-def _measure_rows(host_config: str, backend: str | None,
-                  n_devices: int) -> list[dict]:
-    """Serial + batched(xla/pallas) walls on the current process's exec
-    backend, as ``table9_engine_compare`` measurement rows."""
+def _measure_rows(host_config: str, backend: str | None) -> list[dict]:
+    """Serial + batched walls on the current process's exec backend, as
+    ``table9_engine_compare`` measurement rows naming the platform."""
+    import jax
+    platform = jax.devices()[0].platform
+    n_devices = jax.device_count() if backend == "mesh" else 1
     wall_p = _timeit(lambda: run("python"))
-    rows = [{"host_config": host_config, "engine": "python",
-             "arrival_backend": None, "backend": "serial", "n_devices": 1,
-             "wall_s": round(wall_p, 3), "speedup_vs_python": 1.0}]
-    for ab in ("xla", "pallas"):
-        w = _timeit(lambda: run("batched", arrival_backend=ab,
-                                backend=backend))
-        rows.append({"host_config": host_config, "engine": "batched",
-                     "arrival_backend": ab, "backend": backend or "local",
-                     "n_devices": n_devices, "wall_s": round(w, 3),
-                     "speedup_vs_python": round(wall_p / w, 3)})
-    return rows
+    w = _timeit(lambda: run("batched", backend=backend))
+    return [{"host_config": host_config, "engine": "python",
+             "backend": "serial", "platform": "cpu", "n_devices": 1,
+             "wall_s": round(wall_p, 3), "speedup_vs_python": 1.0},
+            {"host_config": host_config, "engine": "batched",
+             "backend": backend or "local", "platform": platform,
+             "n_devices": n_devices, "wall_s": round(w, 3),
+             "speedup_vs_python": round(wall_p / w, 3)}]
 
 
 def _mesh_probe() -> None:
     """Subprocess entry (``--mesh-probe``): must run under
+    ``JAX_PLATFORMS=cpu`` and
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``. Emits the
     fabricated-host measurement rows as one marked JSON line."""
     import json
 
     import jax
-    n_dev = jax.device_count()
-    rows = _measure_rows(
-        f"fabricated-{n_dev}dev-mesh", backend="mesh", n_devices=n_dev)
+    rows = _measure_rows(f"fabricated-{jax.device_count()}dev-mesh",
+                         backend="mesh")
     print(_PROBE_MARK + json.dumps(rows), flush=True)
 
 
 def _probe_manycore_rows() -> list[dict]:
-    """Spawn the fabricated many-core probe; [] if it fails (recorded
-    honestly — never fabricate a measurement)."""
+    """Run the fabricated many-core probe in a CPU-only child (so it
+    never contends for an accelerator this process holds); a child that
+    fails fails the comparison."""
     import json
     import subprocess
 
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
                          f" --xla_force_host_platform_device_count="
                          f"{FABRICATED_DEVICES}").strip(),
@@ -203,66 +190,43 @@ def _probe_manycore_rows() -> list[dict]:
     for line in proc.stdout.splitlines():
         if line.startswith(_PROBE_MARK):
             return json.loads(line[len(_PROBE_MARK):])
-    print(f"mesh probe failed (rc={proc.returncode}):\n"
-          f"{proc.stderr[-2000:]}", file=sys.stderr)
-    return []
+    raise RuntimeError(f"mesh probe failed (rc={proc.returncode}):\n"
+                       f"{proc.stderr[-2000:]}")
 
 
 def _analysis(rows: list[dict]) -> str:
-    """The honest one-paragraph record the acceptance criteria ask for
-    when no batched config beats serial (and the flip rationale when one
-    does)."""
+    """One-paragraph reading of the rows: which batched configuration
+    was fastest, on which platform, and what fabricated devices mean."""
     best = max((r for r in rows if r["engine"] == "batched"),
                key=lambda r: r["speedup_vs_python"], default=None)
     if best is None:
         return "no batched rows measured"
-    ncpu = os.cpu_count() or 1
-    best_p = max((r for r in rows if r["arrival_backend"] == "pallas"),
-                 key=lambda r: r["speedup_vs_python"], default=None)
-    if best["speedup_vs_python"] > 1.0:
-        engine_part = (
-            f"batched engine measured {best['speedup_vs_python']}x vs "
-            f"serial ({best['arrival_backend']} arrival path, "
-            f"{best['backend']} backend, {best['n_devices']} devices) — "
-            f"fast-mode engine default is batched")
-    else:
-        engine_part = (
-            f"best batched config is {best['speedup_vs_python']}x vs "
-            f"serial — set BENCH_TABLE9_ENGINE=python on this host")
-    if best_p is not None and best_p["speedup_vs_python"] > 1.0:
-        pallas_part = (
-            f"; pallas arrival path measured "
-            f"{best_p['speedup_vs_python']}x — worth flipping "
-            f"BENCH_ARRIVAL_BACKEND=pallas for this config")
-    else:
-        pallas_part = (
-            f"; the pallas arrival path did NOT beat serial here (best "
-            f"{best_p['speedup_vs_python'] if best_p else 'n/a'}x on a "
-            f"{ncpu}-core host): the kernel runs in INTERPRET mode on "
-            f"CPU (no compiled lowering in this JAX build), so fusing "
-            f"cannot remove the per-primitive tax, and fabricated "
-            f"many-core devices time-share the same physical cores "
-            f"(mesh rows measure sharding overhead, not parallel "
-            f"speedup). BENCH_ARRIVAL_BACKEND default stays xla; the "
-            f"kernel path is expected to win on TPU/GPU (mosaic/triton) "
-            f"or real many-core hosts — the bit-identity tests keep it "
-            f"safe to flip per-host")
-    return engine_part + pallas_part
+    verdict = ("batched engine faster than serial"
+               if best["speedup_vs_python"] > 1.0
+               else "serial oracle faster — set BENCH_TABLE9_ENGINE=python "
+                    "on this host")
+    return (f"best batched config: {best['speedup_vs_python']}x vs serial "
+            f"({best['backend']} backend, {best['n_devices']} "
+            f"{best['platform']} devices; {verdict}). Fabricated CPU "
+            f"devices time-share the host's {os.cpu_count() or 1} cores, "
+            f"so mesh rows measure sharding overhead, not parallel "
+            f"speedup.")
 
 
 def compare() -> list[dict]:
-    """Measure every engine x arrival-backend combination on this host
-    and on a fabricated many-core mesh host; record the rows (+ honest
-    analysis) in results/BENCH_sweep.json ``table9_engine_compare``."""
-    from benchmarks.common import record_kv
-    from repro.kernels.backend import pallas_mode
+    """Measure both engines on this host and on a fabricated many-core
+    CPU mesh; record the rows (+ analysis) in results/BENCH_sweep.json
+    ``table9_engine_compare``."""
+    import jax
 
-    rows = _measure_rows("local", backend=None, n_devices=1)
+    from benchmarks.common import record_kv
+
+    rows = _measure_rows("local", backend=None)
     rows += _probe_manycore_rows()
 
-    # numeric drift check rides along: batched+pallas vs serial rows
+    # numeric drift check rides along: batched vs serial rows
     rows_p = run("python")
-    rows_b = run("batched", arrival_backend="pallas")
+    rows_b = run("batched")
     for a, b in zip(rows_p, rows_b):
         drift = abs(a["energy_eff"] - b["energy_eff"])
         print(f"{a['trace']:22s} {a['dispatch']:14s} "
@@ -272,26 +236,25 @@ def compare() -> list[dict]:
     grid, _ = _grid()
     wall_p = next(r["wall_s"] for r in rows if r["engine"] == "python")
     local_b = next(r for r in rows
-                   if r["engine"] == "batched" and r["backend"] == "local"
-                   and r["arrival_backend"] == "xla")
+                   if r["engine"] == "batched" and r["backend"] == "local")
+    dev = jax.devices()[0]
     record_kv("table9_engine_compare",
-              # back-compat summary keys (local host, xla arrival path)
+              # summary keys (this process's platform, local backend)
               python_wall_s=wall_p,
               batched_wall_s=local_b["wall_s"],
               batched_speedup=local_b["speedup_vs_python"],
               cells=len(DISPATCHERS) * sum(len(apps) for _, apps in grid),
               fast=FAST,
               host_cpu_count=os.cpu_count(),
-              pallas_mode=pallas_mode(),
+              platform=dev.platform, device_kind=dev.device_kind,
               default_engine=os.environ.get("BENCH_TABLE9_ENGINE",
                                             DEFAULT_ENGINE),
               rows=rows,
               analysis=_analysis(rows))
     for r in rows:
         print(f"{r['host_config']:22s} {r['engine']:8s} "
-              f"arrival={str(r['arrival_backend']):7s} "
-              f"backend={r['backend']:7s} dev={r['n_devices']} "
-              f"wall={r['wall_s']:.1f}s "
+              f"backend={r['backend']:7s} {r['platform']} "
+              f"dev={r['n_devices']} wall={r['wall_s']:.1f}s "
               f"speedup={r['speedup_vs_python']:.2f}x")
     print(_analysis(rows))
     return rows_p

@@ -15,9 +15,6 @@ Transition backends (``transition=`` on the solvers):
               Retained as the oracle for the structured paths.
   structured  exact O(N log N) per interval via the monotone segment
               decomposition below (default).
-  kernel      the structured transition packaged as a scan-based Pallas
-              kernel (``repro.kernels.minplus``): the whole row and its
-              scan tables stay in VMEM for the duration of the step.
 
 Structured decomposition (the fig2 compute-wall fix): the churn cost
 
@@ -355,7 +352,7 @@ def minplus_step_structured(F: jnp.ndarray, yc_prev: jnp.ndarray,
         lambda: minplus_step_jnp(F, yc_prev, yc_cur, coeffs))
 
 
-TRANSITIONS = ("dense", "structured", "kernel")
+TRANSITIONS = ("dense", "structured")
 
 
 def _transition_step(transition: str):
@@ -366,9 +363,6 @@ def _transition_step(transition: str):
         return minplus_step_jnp
     if transition == "structured":
         return functools.partial(minplus_step_structured, check=False)
-    if transition == "kernel":
-        from repro.kernels.minplus import ops as minplus_ops
-        return minplus_ops.minplus_step_structured
     raise ValueError(f"unknown transition {transition!r}; "
                      f"expected one of {TRANSITIONS}")
 
@@ -507,21 +501,15 @@ def _solve_batch(W_b: jnp.ndarray, we_b: jnp.ndarray, wc_b: jnp.ndarray,
                                                       coeffs_b)
 
 
-def _resolve_transition(transition: str, use_kernel: bool) -> str:
-    """Back-compat shim: ``use_kernel=True`` predates the ``transition``
-    selector and now means the structured Pallas kernel."""
-    if use_kernel:
-        transition = "kernel"
+def _check_transition(transition: str) -> None:
     if transition not in TRANSITIONS:
         raise ValueError(f"unknown transition {transition!r}; "
                          f"expected one of {TRANSITIONS}")
-    return transition
 
 
 def solve_dp_batch(work_batch: np.ndarray, fleet: FleetParams,
                    energy_weights, allow_cpu: bool = True,
                    allow_fpga: bool = True, n_levels: int | None = None,
-                   use_kernel: bool = False,
                    transition: str = "structured") -> list[DpSolution]:
     """Batched `solve_dp`: row i of ``work_batch`` is solved with
     ``energy_weights[i]`` in a handful of vmapped dispatches. Build the
@@ -534,13 +522,13 @@ def solve_dp_batch(work_batch: np.ndarray, fleet: FleetParams,
     their own peak-demand level count (rounded up to a multiple of 128)
     and each bucket dispatches once — O(n_levels^2) per interval means
     solving a calm trace at a bursty trace's level count wastes orders
-    of magnitude of work. The structured/kernel transitions are
+    of magnitude of work. The structured transition is
     ~linear in the level count, where the dominant cost is instead the
     per-program overhead (trace + lower + compile-cache round trip) of
     every distinct bucket shape, so all rows share one bucket sized to
     the batch peak: one program per call. Pass an explicit ``n_levels``
     to override either policy."""
-    transition = _resolve_transition(transition, use_kernel)
+    _check_transition(transition)
     _check_structure(fleet)
     W_np = np.asarray(work_batch, dtype=np.float64)
     if W_np.ndim != 2:
@@ -583,10 +571,9 @@ def solve_dp_batch(work_batch: np.ndarray, fleet: FleetParams,
 def solve_dp(work_cpu_s: np.ndarray, fleet: FleetParams,
              energy_weight: float = 1.0, allow_cpu: bool = True,
              allow_fpga: bool = True, n_levels: int | None = None,
-             use_kernel: bool = False,
              transition: str = "structured") -> DpSolution:
     """Solve the idealized scheduler by min-plus DP and evaluate the path."""
-    transition = _resolve_transition(transition, use_kernel)
+    _check_transition(transition)
     _check_structure(fleet)
     W = jnp.asarray(work_cpu_s, dtype=jnp.float32)
     Ts, S = fleet.T_s, fleet.S

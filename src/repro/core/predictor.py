@@ -34,30 +34,23 @@ import jax.numpy as jnp
 from .breakeven import ObjectiveCoeffs
 
 
-_PFX_BLOCK = 32
-
-
 def _prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
     """Inclusive prefix sum along the last axis, batch-friendly.
 
-    XLA:CPU lowers `cumsum`/`associative_scan` to long sequential or
-    many-stage op chains that dominate the rate simulator's per-interval
-    tick when vmapped over sweep cells. For block-aligned sizes this uses
-    a two-level blocked scan instead — prefix-within-block via one small
-    triangular matmul plus a tiny cross-block offset scan — a handful of
-    well-vectorized ops and negligible flops."""
+    A log-step (Hillis-Steele) scan: ceil(log2 n) shifted elementwise
+    adds, so every element is summed in an order fixed by n alone. Its
+    result therefore does not depend on how many cells share a vmapped
+    program -- a `MeshBackend` device's lane shard gives bit for bit what
+    the whole chunk gives on `LocalBackend`. (A triangular matmul is
+    not: XLA:CPU's accumulation order follows the batch size, and a TPU
+    takes one bf16 pass for an f32 matmul by default.)"""
     n = x.shape[-1]
-    b = _PFX_BLOCK
-    if n < 2 * b or n % b:
-        return jax.lax.associative_scan(jnp.add, x, axis=-1)
-    k = n // b
-    blocks = x.reshape(*x.shape[:-1], k, b)
-    incl = jnp.triu(jnp.ones((b, b), x.dtype))       # incl[i, j]=1 for i<=j
-    within = blocks @ incl                           # prefix within block
-    sums = within[..., -1]                           # block totals (… , k)
-    strict = jnp.triu(jnp.ones((k, k), x.dtype), 1)  # exclusive offsets
-    offsets = sums @ strict
-    return (within + offsets[..., None]).reshape(x.shape)
+    pad = [(0, 0)] * (x.ndim - 1)
+    s = 1
+    while s < n:
+        x = x + jnp.pad(x[..., :n - s], pad + [(s, 0)])
+        s *= 2
+    return x
 
 
 def amortization_vector(life_sum: jnp.ndarray, life_cnt: jnp.ndarray,

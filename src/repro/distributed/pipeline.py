@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -36,10 +35,10 @@ def pipeline_forward(mesh: Mesh, stage_fn, stage_params, microbatches,
     steps = m + n_stages - 1
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     def run(params_local, micro):
         params_local = jax.tree_util.tree_map(lambda a: a[0], params_local)
         sid = jax.lax.axis_index(axis)

@@ -61,14 +61,8 @@ class FleetTenantAcc(NamedTuple):
 
 def _fleet_arrival(es: EventScalars, fstat, code, acode, w_f: int, is_f,
                    idxW, ta_size, ta_dl, adm_rate, adm_burst, adm_quota,
-                   arrival_backend, carry, xs):
-    """One tenant-tagged arrival: admission -> (gated) dispatch -> tally.
-
-    ``arrival_backend="pallas"`` routes the dispatch through the fused
-    `repro.kernels.arrival` kernel as a length-1 block (admission
-    decisions interleave between arrivals, so the fleet path cannot hand
-    the kernel a whole block at once — the per-arrival tenant scalars
-    and the admission gate change the `EventScalars` every step)."""
+                   carry, xs):
+    """One tenant-tagged arrival: admission -> (gated) dispatch -> tally."""
     c, tok, last, cnt, fa = carry
     t, tid = xs
     real = jnp.isfinite(t)
@@ -91,20 +85,14 @@ def _fleet_arrival(es: EventScalars, fstat, code, acode, w_f: int, is_f,
     # padded arrivals become t = +inf — an exact no-op in both kernels
     es_a = es._replace(size=ta_size[tid], deadline=ta_dl[tid])
     t_eff = jnp.where(admit, t, jnp.inf)
-    if arrival_backend == "pallas":
-        from repro.kernels.arrival.ops import arrival_block
-        c2 = arrival_block(es_a, fstat, code, w_f, c,
-                           jnp.reshape(t_eff, (1,)))
-    elif fstat.enabled:
-        c2 = _arrival_fail(es_a, fstat, code, w_f, is_f, idxW, c, t_eff)
-    else:
-        c2 = _arrival_step(es_a, code, w_f, is_f, idxW, c, t_eff)
     if fstat.enabled:
+        c2 = _arrival_fail(es_a, fstat, code, w_f, is_f, idxW, c, t_eff)
         served_f = c2.fail.work_f > c.fail.work_f
         served_c = c2.fail.work_c > c.fail.work_c
         missed = (jnp.any(c2.miss_slot != c.miss_slot)
                   | (c2.fail.dropped > c.fail.dropped))
     else:
+        c2 = _arrival_step(es_a, code, w_f, is_f, idxW, c, t_eff)
         served_f = jnp.any(c2.serv_slot[:w_f] != c.serv_slot[:w_f])
         served_c = jnp.any(c2.serv_slot[w_f:] != c.serv_slot[w_f:])
         missed = jnp.any(c2.miss_slot != c.miss_slot)
@@ -117,10 +105,9 @@ def _fleet_arrival(es: EventScalars, fstat, code, acode, w_f: int, is_f,
     return (c2, tok, last, cnt, fa), None
 
 
-def _simulate_fleet_one(n_max: int, w_f: int, w_c: int, fstat,
-                        arrival_backend: str, es, code, acode, times,
-                        tids, tick_t, is_tick, ta_size, ta_dl, adm_rate,
-                        adm_burst, adm_quota) -> tuple:
+def _simulate_fleet_one(n_max: int, w_f: int, w_c: int, fstat, es, code,
+                        acode, times, tids, tick_t, is_tick, ta_size,
+                        ta_dl, adm_rate, adm_burst, adm_quota) -> tuple:
     """One fleet cell over the flat tenant-tagged entry stream. Mirrors
     `repro.sim.events_batched._simulate_one` (same worker-table init,
     same entry scan, same final drain + `Accum` derivation) with the
@@ -153,7 +140,7 @@ def _simulate_fleet_one(n_max: int, w_f: int, w_c: int, fstat,
 
     step = functools.partial(_fleet_arrival, es, fstat, code, acode, w_f,
                              is_f, idxW, ta_size, ta_dl, adm_rate,
-                             adm_burst, adm_quota, arrival_backend)
+                             adm_burst, adm_quota)
 
     def entry(state, xs):
         c, ts, tok, last, cnt, fa = state
@@ -189,21 +176,18 @@ def _simulate_fleet_one(n_max: int, w_f: int, w_c: int, fstat,
 
 
 def _simulate_fleet_cells_core(n_max: int, w_fpga: int, w_cpu: int,
-                               fstat, arrival_backend: str, es, codes,
-                               acodes, times, tids, tick_t, is_tick,
-                               ta_size, ta_dl, adm_rate, adm_burst,
-                               adm_quota) -> tuple:
+                               fstat, es, codes, acodes, times, tids,
+                               tick_t, is_tick, ta_size, ta_dl, adm_rate,
+                               adm_burst, adm_quota) -> tuple:
     """Unjitted cell-batched core (vmap over the cell axis), exposed so
     `repro.sim.exec.MeshBackend` can `shard_map` it over a device mesh;
     `_simulate_fleet_cells` is its jitted single-device twin."""
     return jax.vmap(functools.partial(
-        _simulate_fleet_one, n_max, w_fpga, w_cpu, fstat,
-        arrival_backend))(
+        _simulate_fleet_one, n_max, w_fpga, w_cpu, fstat))(
         es, codes, acodes, times, tids, tick_t, is_tick, ta_size, ta_dl,
         adm_rate, adm_burst, adm_quota)
 
 
 _simulate_fleet_cells = functools.partial(
-    jax.jit, static_argnames=("n_max", "w_fpga", "w_cpu", "fstat",
-                              "arrival_backend"))(
+    jax.jit, static_argnames=("n_max", "w_fpga", "w_cpu", "fstat"))(
     _simulate_fleet_cells_core)

@@ -1,22 +1,18 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
-Four kernels, each with the standard layout (<name>.py kernel with
+Three kernels, each with the standard layout (<name>.py kernel with
 pl.pallas_call; ops.py jit'd wrapper; ref.py pure-jnp oracle):
 
   minplus/        min-plus DP transition for the pareto-optimal scheduler
                   (transition matrix generated in-registers: O(N^2) compute
                   on O(N) HBM traffic)
   spork_predict/  Alg. 2 expected-objective scan over candidates x bins
-                  (the simulator's per-interval hot loop)
   decode_attn/    GQA flash-decode attention with online softmax over KV
                   blocks (the serving engine's hot-spot)
-  arrival/        the batched DES arrival step (three-reduction dispatch
-                  core + worker-table update) fused into one kernel;
-                  selected per-sweep via arrival_backend=("xla"|"pallas")
 
-backend.py owns execution-mode selection: `pallas_mode()` probes
-whether this host can compile Pallas (mosaic on TPU, triton on GPU)
-and falls back to interpret mode otherwise; `REPRO_PALLAS_MODE`
-overrides. Every ops.py wrapper routes through it instead of assuming
-interpret=True.
+backend.py owns the execution mode: `use_interpret()` is False on a TPU,
+where every kernel compiles through Mosaic (a lowering failure raises),
+and True elsewhere. Every kernel lowers for TPU v5e
+(tests/test_tpu_compile.py compiles each one for a described chip).
 """
+

@@ -1,91 +1,23 @@
-"""Pallas execution-mode detection shared by every kernel package.
+"""Pallas execution mode, decided by the platform.
 
-The repo's kernels (`kernels.minplus`, `kernels.arrival`) are written to
-run in two modes:
+Every kernel in `repro.kernels` runs in one of two modes:
 
-  * **compiled** — lowered by a real Pallas backend: Mosaic on TPU,
-    Triton on GPU. This is where the fusion argument (one kernel
-    invocation instead of hundreds of XLA primitives) actually buys
-    wall time.
-  * **interpret** — `pallas_call(..., interpret=True)`: the kernel body
-    is traced as ordinary JAX ops with refs emulated, so it runs
-    anywhere XLA runs (including CPU CI containers), bit-identical to
-    the compiled semantics but with no fusion win.
+  * **compiled** — lowered by Mosaic for the TPU (``interpret=False``).
+    A kernel that fails to lower raises; nothing falls back.
+  * **interpret** — ``pallas_call(..., interpret=True)``: the kernel
+    body is traced as ordinary JAX ops with refs emulated, so the same
+    semantics run on the CPU backend the tests use.
 
-Historically the minplus kernels hard-coded ``interpret = backend !=
-"tpu"``. This module replaces that with one autodetected, probed
-answer: `pallas_mode()` names the mode (``"mosaic"`` / ``"triton"`` /
-``"interpret"``), verified by actually compiling a trivial kernel once
-per process — a backend that *claims* Pallas support but fails to
-lower falls back to interpret instead of crashing the sweep. Benchmarks
-record the mode in their rows (results/roofline.json ``pallas_mode``)
-so a "kernel" measurement is never mistaken for a compiled-mode one.
-
-``REPRO_PALLAS_MODE=interpret`` forces interpret mode (used by CI to
-pin the equivalence suites to the emulated path);
-``REPRO_PALLAS_MODE=compiled`` skips the probe's fallback and raises if
-compilation fails (debugging aid).
+`use_interpret` is the one place that decides: compiled on a TPU,
+interpret everywhere else. There is no probe and no override.
 """
 
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
-import jax.numpy as jnp
-
-ENV_VAR = "REPRO_PALLAS_MODE"
-
-#: jax.default_backend() -> the Pallas lowering that serves it. XLA:CPU
-#: has no compiled Pallas path in this JAX version (Triton-on-CPU is
-#: probed anyway in case a newer runtime provides it — the probe, not
-#: this table, is the source of truth).
-_COMPILED_MODES = {"tpu": "mosaic", "gpu": "triton", "cuda": "triton",
-                   "rocm": "triton"}
-
-
-def _probe_compiled() -> bool:
-    """Compile + run a trivial Pallas kernel with ``interpret=False``.
-    Any failure (missing lowering, driver mismatch, unsupported op set)
-    means the compiled mode is unusable on this host."""
-    from jax.experimental import pallas as pl
-
-    def _k(x_ref, o_ref):
-        o_ref[:] = x_ref[:] + 1.0
-
-    try:
-        out = pl.pallas_call(
-            _k,
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-            interpret=False,
-        )(jnp.zeros((8, 128), jnp.float32))
-        return bool(out[0, 0] == 1.0)
-    except Exception:
-        return False
-
-
-@functools.lru_cache(maxsize=1)
-def pallas_mode() -> str:
-    """The Pallas execution mode for this process: ``"mosaic"``,
-    ``"triton"`` or ``"interpret"`` (safe fallback). Probed once and
-    cached; ``REPRO_PALLAS_MODE`` overrides."""
-    forced = os.environ.get(ENV_VAR, "")
-    if forced == "interpret":
-        return "interpret"
-    candidate = _COMPILED_MODES.get(jax.default_backend())
-    if candidate is None and forced != "compiled":
-        return "interpret"
-    if _probe_compiled():
-        return candidate or "triton"
-    if forced == "compiled":
-        raise RuntimeError(
-            f"REPRO_PALLAS_MODE=compiled but the trivial Pallas probe "
-            f"failed to compile on backend {jax.default_backend()!r}")
-    return "interpret"
 
 
 def use_interpret() -> bool:
-    """True when kernels should pass ``interpret=True`` to
-    `pallas_call` (no compiled Pallas backend on this host)."""
-    return pallas_mode() == "interpret"
+    """True unless JAX's default backend is a TPU, where every kernel
+    compiles through Mosaic."""
+    return jax.default_backend() != "tpu"

@@ -1,1 +1,1 @@
-from .ops import minplus_step, minplus_step_structured  # noqa: F401
+from .ops import minplus_step  # noqa: F401

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import use_interpret
+
 BLOCK = 128
 # Large *positive* sentinel: padded source levels must never win the min.
 _PAD_HI = 3.0e38
@@ -42,10 +44,10 @@ def _kernel(params_ref, f_ref, ycp_ref, ycc_ref, out_ref, arg_ref, *,
     ycp = ycp_ref[0, :]                   # (block,)
     ycc = ycc_ref[0, :]                   # (block,) destination levels j
 
-    ii = (i_blk * block
-          + jax.lax.broadcasted_iota(jnp.float32, (block, block), 0))
-    jj = (pl.program_id(0) * block
-          + jax.lax.broadcasted_iota(jnp.float32, (block, block), 1))
+    ii = (i_blk * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 0)).astype(jnp.float32)
+    jj = (pl.program_id(0) * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 1)).astype(jnp.float32)
     relu = lambda x: jnp.maximum(x, 0.0)
     trans = (af * relu(jj - ii) + df * relu(ii - jj)
              + ac * relu(ycc[None, :] - ycp[:, None])
@@ -55,7 +57,10 @@ def _kernel(params_ref, f_ref, ycp_ref, ycc_ref, out_ref, arg_ref, *,
     vals = jnp.where(ii < n_valid, vals, _PAD_HI)
 
     local_min = jnp.min(vals, axis=0)
-    local_arg = (i_blk * block + jnp.argmin(vals, axis=0)).astype(jnp.int32)
+    # first minimizer, spelled out: Mosaic's argmin does not promise the
+    # lowest index on ties (levels are exact small integers in f32)
+    local_arg = jnp.min(jnp.where(vals == local_min[None, :], ii, _PAD_HI),
+                        axis=0).astype(jnp.int32)
 
     @pl.when(i_blk == 0)
     def _init():
@@ -74,9 +79,8 @@ def _kernel(params_ref, f_ref, ycp_ref, ycc_ref, out_ref, arg_ref, *,
 def minplus_pallas(F: jnp.ndarray, yc_prev: jnp.ndarray, yc_cur: jnp.ndarray,
                    params: jnp.ndarray, interpret: bool | None = None):
     """F, yc_prev, yc_cur: (N,) float32; params: (4,) [af, df, ac, dc].
-    ``interpret=None`` autodetects via `repro.kernels.backend`."""
+    ``interpret=None`` follows the platform (`repro.kernels.backend`)."""
     if interpret is None:
-        from repro.kernels.backend import use_interpret
         interpret = use_interpret()
     n = F.shape[0]
     n_pad = ((n + BLOCK - 1) // BLOCK) * BLOCK

@@ -1,26 +1,15 @@
 """Jit'd wrapper for the min-plus transition kernel.
 
 `minplus_step` has the exact signature of the jnp oracle
-(repro.core.dp.minplus_step_jnp) so the DP can swap implementations with a
-flag. The execution mode is probed, not assumed: wherever
-`repro.kernels.backend.pallas_mode` finds a working compiled lowering
-(Mosaic on TPU, Triton on GPU — or Triton-on-CPU if the runtime grows
-one) the kernels compile; everywhere else they run in interpret mode
-(the kernel body traced as ordinary XLA ops).
+(repro.core.dp.minplus_step_jnp). The kernel compiles on a TPU and runs
+in interpret mode elsewhere (`repro.kernels.backend.use_interpret`).
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.backend import use_interpret
-
 from .minplus import minplus_pallas
-from .structured import minplus_structured_pallas
-
-
-def _interpret() -> bool:
-    return use_interpret()
 
 
 def _pack(coeffs) -> jnp.ndarray:
@@ -34,15 +23,4 @@ def _pack(coeffs) -> jnp.ndarray:
 def minplus_step(F: jnp.ndarray, yc_prev: jnp.ndarray, yc_cur: jnp.ndarray,
                  coeffs) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Dense O(N^2) transition kernel (the original HBM-light contraction)."""
-    return minplus_pallas(F, yc_prev, yc_cur, _pack(coeffs),
-                          interpret=_interpret())
-
-
-def minplus_step_structured(F: jnp.ndarray, yc_prev: jnp.ndarray,
-                            yc_cur: jnp.ndarray,
-                            coeffs) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Structured O(N log N) transition kernel; requires non-increasing
-    y_c vectors (guaranteed by `core.dp._stage_tables`). This is the
-    ``transition="kernel"`` backend of the DP solvers."""
-    return minplus_structured_pallas(F, yc_prev, yc_cur, _pack(coeffs),
-                                     interpret=_interpret())
+    return minplus_pallas(F, yc_prev, yc_cur, _pack(coeffs))

@@ -6,6 +6,3 @@ follow the standard kernels/<name>/{ref,ops} layout.
 """
 
 from repro.core.dp import minplus_step_jnp as minplus_step_ref  # noqa: F401
-from repro.core.dp import (  # noqa: F401
-    minplus_step_structured as minplus_step_structured_ref,
-)

@@ -2,16 +2,11 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.breakeven import ObjectiveCoeffs
 
 from .spork_predict import spork_predict_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def expected_objective(hist: jnp.ndarray, coeffs: ObjectiveCoeffs,
@@ -27,5 +22,5 @@ def expected_objective(hist: jnp.ndarray, coeffs: ObjectiveCoeffs,
         jnp.asarray(coeffs.co_over, jnp.float32),
         jnp.asarray(coeffs.co_under, jnp.float32),
         jnp.sum(hist).astype(jnp.float32), lo, hi])
-    out = spork_predict_pallas(hist, amort, params, interpret=_interpret())
+    out = spork_predict_pallas(hist, amort, params)
     return jnp.where(out >= 1.0e38, jnp.inf, out)

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import use_interpret
+
 BLOCK = 128
 _INF = 3.0e38
 
@@ -40,16 +42,17 @@ def _kernel(params_ref, hist_ref, amort_ref, out_ref, *, block: int):
     hi = params_ref[0, 5]
 
     p = hist_ref[0, :] / jnp.maximum(total, 1.0)        # (block,) bin probs
-    cc = (c_blk * block
-          + jax.lax.broadcasted_iota(jnp.float32, (block, block), 0))
-    bb = (b_blk * block
-          + jax.lax.broadcasted_iota(jnp.float32, (block, block), 1))
+    cc = (c_blk * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 0)).astype(jnp.float32)
+    bb = (b_blk * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, block), 1)).astype(jnp.float32)
     relu = lambda x: jnp.maximum(x, 0.0)
     per = (co_min * jnp.minimum(cc, bb) + co_over * relu(cc - bb)
            + co_under * relu(bb - cc))                  # (c, b)
     partial = jax.lax.dot_general(
         per, p[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]       # (block,)
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)[:, 0]       # (block,)
 
     @pl.when(b_blk == 0)
     def _init():
@@ -61,8 +64,8 @@ def _kernel(params_ref, hist_ref, amort_ref, out_ref, *, block: int):
 
     @pl.when(b_blk == nb - 1)
     def _finalize():
-        cand = (c_blk * block
-                + jax.lax.broadcasted_iota(jnp.float32, (1, block), 1))[0, :]
+        cand = (c_blk * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1)).astype(jnp.float32)[0, :]
         j = out_ref[0, :] + amort_ref[0, :]
         mask = (cand >= lo) & (cand <= hi)
         out_ref[0, :] = jnp.where(mask, j, _INF)
@@ -70,9 +73,12 @@ def _kernel(params_ref, hist_ref, amort_ref, out_ref, *, block: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def spork_predict_pallas(hist: jnp.ndarray, amort: jnp.ndarray,
-                         params: jnp.ndarray, interpret: bool = True):
+                         params: jnp.ndarray, interpret: bool | None = None):
     """hist, amort: (N,) float32; params: (6,) [co_min, co_over, co_under,
-    total, lo, hi]. Returns J: (N,) float32 (masked entries ~ +inf)."""
+    total, lo, hi]. Returns J: (N,) float32 (masked entries ~ +inf).
+    ``interpret=None`` follows the platform (`repro.kernels.backend`)."""
+    if interpret is None:
+        interpret = use_interpret()
     n = hist.shape[0]
     n_pad = ((n + BLOCK - 1) // BLOCK) * BLOCK
     pad = n_pad - n

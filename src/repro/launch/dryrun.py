@@ -1,13 +1,11 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape) cell on
 the production meshes and record memory/cost/collective analyses.
 
-The two lines above MUST stay first: jax locks the device count at first
-initialization, and the dry-run needs 512 placeholder host devices so
-jax.make_mesh can build the (2, 16, 16) production mesh. Nothing here
-allocates real arrays — inputs are ShapeDtypeStructs (launch.specs) and
+`main` pins JAX to the CPU backend with 512 placeholder host devices
+(``jax_platforms=cpu``, ``jax_num_cpu_devices=512``) before anything
+touches a device, so jax.make_mesh can build the (2, 16, 16) production
+mesh and the run never claims an accelerator. Nothing here allocates
+real arrays — inputs are ShapeDtypeStructs (launch.specs) and
 compilation is AOT.
 
 Usage:
@@ -118,7 +116,13 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
     return rec
 
 
+#: placeholder host devices: enough for the (2, 16, 16) multi-pod mesh
+N_PLACEHOLDER_DEVICES = 512
+
+
 def main() -> None:
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", N_PLACEHOLDER_DEVICES)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -13,7 +13,7 @@ encodes the spin-up latency, the ceil() in the target a pass-through,
 and the deadline-miss indicator a softplus of capacity shortfall.
 `jax.grad` flows through the whole `lax.scan` (one interval per step).
 The relaxation is dtype-agnostic on purpose: the gradient-correctness
-tests re-run it in float64 (``jax.experimental.enable_x64``) to compare
+tests re-run it in float64 (``jax.enable_x64``) to compare
 against central finite differences at tight tolerance.
 
 The continuous optimum is then *integer-refined*: a handful of nearby

@@ -31,7 +31,7 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core.metrics import Report, RunTotals, report
 from repro.core.workers import DEFAULT_FLEET, FleetParams
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_DEVICE_KIND, chip_peaks
 from repro.sim.events import EventSim
 
 
@@ -43,10 +43,11 @@ class ArchServiceModel:
 
 
 def analytic_token_latency(arch: str) -> float:
-    """Bandwidth-bound decode floor: active params (bf16) / HBM bandwidth."""
+    """Bandwidth-bound decode floor on the target chip: active params
+    (bf16) / HBM bandwidth."""
     cfg = get_config(arch, "full")
     active = cfg.param_count(active_only=True)
-    return active * 2.0 / HBM_BW
+    return active * 2.0 / chip_peaks(TARGET_DEVICE_KIND).hbm_bw
 
 
 def roofline_token_latency(arch: str,
@@ -63,9 +64,10 @@ def roofline_token_latency(arch: str,
     byts = rec.get("hlo_bytes", 0.0)
     if flops <= 0 or byts <= 0:
         return None
-    # per-device terms; batch shares the step
+    # per-device terms on the target chip; batch shares the step
     batch = 128
-    t = max(flops / PEAK_FLOPS_BF16, byts / HBM_BW) / batch
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
+    t = max(flops / peaks.flops_bf16, byts / peaks.hbm_bw) / batch
     return float(t)
 
 

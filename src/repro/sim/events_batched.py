@@ -98,28 +98,6 @@ BLOCK = 128
 # results are discarded), larger grids run in chunks of the cap.
 EV_CHUNK_MAX = 32
 
-#: Implementations of the per-block arrival path. "xla" is the engine's
-#: native `lax.scan` over `_arrival_step`/`_arrival_fail` (the trusted
-#: default); "pallas" routes each block through the fused
-#: `repro.kernels.arrival` kernel (bit-identical; faster only where a
-#: compiled Pallas backend exists — see docs/architecture.md).
-ARRIVAL_BACKENDS = ("xla", "pallas")
-
-#: Environment override for the default arrival backend.
-ARRIVAL_ENV = "BENCH_ARRIVAL_BACKEND"
-
-
-def resolve_arrival_backend(backend: str | None = None) -> str:
-    """Resolve an ``arrival_backend`` argument: explicit value wins,
-    else ``$BENCH_ARRIVAL_BACKEND``, else ``"xla"``."""
-    import os
-    b = backend if backend is not None else os.environ.get(ARRIVAL_ENV,
-                                                           "xla")
-    if b not in ARRIVAL_BACKENDS:
-        raise ValueError(
-            f"arrival_backend must be one of {ARRIVAL_BACKENDS}, got {b!r}")
-    return b
-
 
 class EventScalars(NamedTuple):
     """Traced per-cell parameters (every leaf carries the cell axis in
@@ -662,17 +640,11 @@ def _tick_step(es: EventScalars, fstat: FailStatic, w_f: int, is_f,
     return c, ts
 
 def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
-                  arrival_backend: str, es: EventScalars, code, times,
-                  tick_t, is_tick) -> tuple:
+                  es: EventScalars, code, times, tick_t, is_tick) -> tuple:
     """One cell over the flat entry stream: each entry runs one (padded)
     arrival block through the inner scan, then one gated tick. ``fstat``
     selects the compiled program: disabled cells run the pristine
-    pre-failure path (bit-identical to the engine without the axis).
-    ``arrival_backend`` (static) picks the arrival-block implementation:
-    ``"xla"`` is the native inner scan, ``"pallas"`` the fused
-    `repro.kernels.arrival` kernel (bit-identical by construction — its
-    per-arrival body is this module's own `_arrival_step` /
-    `_arrival_fail`)."""
+    pre-failure path (bit-identical to the engine without the axis)."""
     W = w_f + w_c
     is_f = jnp.arange(W) < w_f
     idxW = jnp.arange(W, dtype=jnp.float32)
@@ -694,12 +666,6 @@ def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
                     life_sum=zf(n_max), life_cnt=zf(n_max), F_prev=zf(),
                     C_prev=zf(), spins=zf(), energy=zf(6))
 
-    if arrival_backend == "pallas":
-        # Trace-time-only import: the kernel package imports this module
-        # for the step functions, so the engine must not import it at
-        # module load (docs/architecture.md, "Kernel layer").
-        from repro.kernels.arrival.ops import arrival_block
-
     def entry(state, xs):
         c, ts = state
         row, tt, tk = xs
@@ -710,10 +676,7 @@ def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
                                      cc, ta), None
             return _arrival_step(es, code, w_f, is_f, idxW, cc, ta), None
 
-        if arrival_backend == "pallas":
-            c = arrival_block(es, fstat, code, w_f, c, row)
-        else:
-            c, _ = jax.lax.scan(inner, c, row)
+        c, _ = jax.lax.scan(inner, c, row)
         return _tick_step(es, fstat, w_f, is_f, c, ts, tt, tk), None
 
     (c, ts), _ = jax.lax.scan(entry, (c0, ts0), (times, tick_t, is_tick))
@@ -743,20 +706,18 @@ def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
 
 
 def _simulate_cells_core(n_max: int, w_fpga: int, w_cpu: int,
-                         fstat: FailStatic, arrival_backend: str,
-                         es: EventScalars, codes, times, tick_t,
-                         is_tick) -> tuple:
+                         fstat: FailStatic, es: EventScalars, codes,
+                         times, tick_t, is_tick) -> tuple:
     """Unjitted cell-batched core (vmap over the cell axis). Exposed so
     `repro.sim.exec.MeshBackend` can `shard_map` it over a device mesh;
     `_simulate_cells` is its jitted single-device twin."""
     return jax.vmap(functools.partial(
-        _simulate_one, n_max, w_fpga, w_cpu, fstat, arrival_backend))(
+        _simulate_one, n_max, w_fpga, w_cpu, fstat))(
         es, codes, times, tick_t, is_tick)
 
 
 _simulate_cells = functools.partial(
-    jax.jit, static_argnames=("n_max", "w_fpga", "w_cpu", "fstat",
-                              "arrival_backend"))(
+    jax.jit, static_argnames=("n_max", "w_fpga", "w_cpu", "fstat"))(
     _simulate_cells_core)
 
 
@@ -889,9 +850,7 @@ def _pad_pow2(n: int, lo: int = 4, hi: int | None = None) -> int:
 
 def simulate_events_batch(cells: Iterable[EventCell], n_max: int = 512,
                           w_fpga: int = 32, w_cpu: int = 64,
-                          backend=None,
-                          arrival_backend: str | None = None
-                          ) -> list[RunTotals]:
+                          backend=None) -> list[RunTotals]:
     """Run every DES cell, one dispatch per (entry-count bucket) group
     chunk; cell order is preserved. Totals carry
     ``breakdown['slot_overflow']`` (0 unless a table region or
@@ -907,7 +866,7 @@ def simulate_events_batch(cells: Iterable[EventCell], n_max: int = 512,
     from repro.sim.exec import execute
     from repro.sim.plan import plan_events
     plan = plan_events(cells, n_max=n_max, w_fpga=w_fpga, w_cpu=w_cpu,
-                       resolve=False, arrival_backend=arrival_backend)
+                       resolve=False)
     return execute(plan, backend).totals()
 
 
@@ -918,13 +877,11 @@ def simulate_events_batched(arrival_times: np.ndarray, size_s: float,
                             deadline_s: float | None = None,
                             allocate_fpgas: bool = True, n_max: int = 512,
                             w_fpga: int = 32, w_cpu: int = 64,
-                            failures: FailureSpec | None = None,
-                            arrival_backend: str | None = None) -> RunTotals:
+                            failures: FailureSpec | None = None) -> RunTotals:
     """Drop-in twin of `events.simulate_events` on the batched engine."""
     cell = EventCell(dispatcher, np.asarray(arrival_times), size_s, fleet,
                      energy_weight=energy_weight, horizon_s=horizon_s,
                      deadline_s=deadline_s, allocate_fpgas=allocate_fpgas,
                      failures=failures)
     return simulate_events_batch([cell], n_max=n_max, w_fpga=w_fpga,
-                                 w_cpu=w_cpu,
-                                 arrival_backend=arrival_backend)[0]
+                                 w_cpu=w_cpu)[0]

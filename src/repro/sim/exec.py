@@ -184,7 +184,6 @@ class MeshBackend(Backend):
         key = (kind, static, n_dev)
         fn = self._fns.get(key)
         if fn is None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             from repro.launch.mesh import make_cell_mesh
@@ -196,9 +195,9 @@ class MeshBackend(Backend):
                 core = fleet_engine._simulate_fleet_cells_core
             else:
                 core = events_batched._simulate_cells_core
-            sharded = shard_map(functools.partial(core, *static),
-                                mesh=mesh, in_specs=P("cells"),
-                                out_specs=P("cells"), check_rep=False)
+            sharded = jax.shard_map(functools.partial(core, *static),
+                                    mesh=mesh, in_specs=P("cells"),
+                                    out_specs=P("cells"), check_vma=False)
             fn = self._fns[key] = jax.jit(sharded)
         return fn
 

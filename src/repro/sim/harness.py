@@ -67,9 +67,9 @@ from repro.sim.ratesim import Accum
 #: Code-version salt folded into every chunk fingerprint. Bump when the
 #: simulator engines change semantics: resuming a checkpoint written by
 #: different engine code must miss, not silently mix results.
-CODE_SALT = "repro-sweep-harness-v3"  # v3: arrival_backend joins the
-                                      # event/fleet chunk statics
-                                      # (Pallas arrival kernel selector)
+CODE_SALT = "repro-sweep-harness-v4"  # v4: the arrival-backend
+                                      # selector left the event/fleet
+                                      # chunk statics
 
 ENV_SKIP_INVARIANTS = "REPRO_SKIP_INVARIANTS"
 

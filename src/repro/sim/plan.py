@@ -52,8 +52,7 @@ from repro.core.workers import FleetParams
 from repro.ft.failures import fail_static
 from repro.policies import get_dispatch_policy, get_rate_policy
 from repro.sim.events_batched import (BLOCK, EV_CHUNK_MAX, _entries,
-                                      _pad_pow2, _scalars,
-                                      resolve_arrival_backend)
+                                      _pad_pow2, _scalars)
 from repro.sim.ratesim import (Accum, FleetScalars, accum_to_totals,
                                static_level_for)
 
@@ -275,8 +274,7 @@ def plan_sweep(cells: Iterable, n_max: int | None = None) -> SweepPlan:
 
 
 def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
-                w_cpu: int = 64, resolve: bool = True,
-                arrival_backend: str | None = None) -> SweepPlan:
+                w_cpu: int = 64, resolve: bool = True) -> SweepPlan:
     """Plan a DES sweep: cells grouped by padded entry-stream length,
     one `ChunkDispatch` per group chunk, arrays laid out exactly as
     `events_batched._simulate_cells` consumes them. ``resolve=False``
@@ -289,14 +287,7 @@ def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     memory is proportional to the whole sweep rather than one chunk.
     At benchmark scale that is megabytes; callers planning very long
     streams x many chunks should slab their cell lists into multiple
-    plans.
-
-    ``arrival_backend`` (``"xla"`` | ``"pallas"`` | None =
-    ``$BENCH_ARRIVAL_BACKEND`` else ``"xla"``) selects the engine's
-    arrival-block implementation; it rides in every dispatch's static
-    tuple, so it reaches both exec backends and the chunk fingerprint
-    (`repro.sim.harness`) unchanged."""
-    arrival_backend = resolve_arrival_backend(arrival_backend)
+    plans."""
     cells = resolve_scenarios(cells) if resolve else list(cells)
     codes = {}
     for i, cl in enumerate(cells):
@@ -355,16 +346,14 @@ def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                 "times": times, "tick_t": tick_t, "is_tick": is_tick,
             }
             dispatches.append(ChunkDispatch(
-                kind="event",
-                static=(n_max, w_fpga, w_cpu, fstat, arrival_backend),
+                kind="event", static=(n_max, w_fpga, w_cpu, fstat),
                 arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
 
     return SweepPlan("event", cells, dispatches, n_max)
 
 
 def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
-               w_cpu: int = 64,
-               arrival_backend: str | None = None) -> SweepPlan:
+               w_cpu: int = 64) -> SweepPlan:
     """Plan a multi-tenant fleet sweep (`repro.fleet.FleetCell` cells):
     the DES plan machinery of `plan_events` with a tenant axis — each
     cell's merged tenant-tagged stream (`repro.fleet.resolve_fleet_cell`)
@@ -381,7 +370,6 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     from repro.fleet.specs import FleetCell, resolve_fleet_cell
     from repro.sim.events_batched import EventCell
 
-    arrival_backend = resolve_arrival_backend(arrival_backend)
     cells = list(cells)
     entries: dict[int, list] = {}
     resolved: dict[int, Any] = {}
@@ -473,8 +461,7 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                 "adm_quota": tables[:, 4],
             }
             dispatches.append(ChunkDispatch(
-                kind="fleet",
-                static=(n_max, w_fpga, w_cpu, fstat, arrival_backend),
+                kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
                 arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
 
     return SweepPlan("fleet", cells, dispatches, n_max)

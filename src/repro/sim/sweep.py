@@ -140,8 +140,7 @@ def sweep(cells: Iterable[SweepCell], n_max: int | None = None,
 def sweep_events(cells: Iterable[EventCell], n_max: int = 512,
                  w_fpga: int = 32, w_cpu: int = 64,
                  backend: str | Backend | None = None,
-                 checkpoint_dir=None, retry=None,
-                 arrival_backend: str | None = None) -> EventSweepResult:
+                 checkpoint_dir=None, retry=None) -> EventSweepResult:
     """Event-level (DES) cells in sweep grids.
 
     The exact discrete-event counterpart of `sweep`: every `EventCell`
@@ -162,15 +161,13 @@ def sweep_events(cells: Iterable[EventCell], n_max: int = 512,
     harden execution exactly as in `sweep` (docs/architecture.md
     "Execution hardening").
     """
-    plan = plan_events(cells, n_max=n_max, w_fpga=w_fpga, w_cpu=w_cpu,
-                       arrival_backend=arrival_backend)
+    plan = plan_events(cells, n_max=n_max, w_fpga=w_fpga, w_cpu=w_cpu)
     return execute(plan, backend, checkpoint_dir=checkpoint_dir, retry=retry)
 
 
 def sweep_fleet(cells, n_max: int = 512, w_fpga: int = 32, w_cpu: int = 64,
                 backend: str | Backend | None = None,
-                checkpoint_dir=None, retry=None,
-                arrival_backend: str | None = None) -> FleetSweepResult:
+                checkpoint_dir=None, retry=None) -> FleetSweepResult:
     """Multi-tenant fleet cells (`repro.fleet.FleetCell`) in sweep grids.
 
     Each cell is N tenants sharing ONE fleet under one dispatch policy
@@ -185,8 +182,7 @@ def sweep_fleet(cells, n_max: int = 512, w_fpga: int = 32, w_cpu: int = 64,
     the default-on invariant guards
     (`repro.sim.harness.check_fleet_result`). ``checkpoint_dir`` /
     ``retry`` harden execution exactly as in `sweep`."""
-    plan = plan_fleet(cells, n_max=n_max, w_fpga=w_fpga, w_cpu=w_cpu,
-                      arrival_backend=arrival_backend)
+    plan = plan_fleet(cells, n_max=n_max, w_fpga=w_fpga, w_cpu=w_cpu)
     return execute(plan, backend, checkpoint_dir=checkpoint_dir, retry=retry)
 
 

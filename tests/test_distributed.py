@@ -19,8 +19,7 @@ from repro.distributed import sharding as shd
 
 @pytest.fixture()
 def mesh16():
-    # AbstractMesh takes ((name, size), ...) pairs on this jax version.
-    m = AbstractMesh((("data", 16), ("model", 16)))
+    m = AbstractMesh((16, 16), ("data", "model"))
     shd.set_mesh(m)
     yield m
     shd.clear_mesh()
@@ -126,18 +125,17 @@ def test_pipeline_forward_matches_sequential():
 def test_hierarchical_psum_equals_flat():
     body = """
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.collectives import hierarchical_psum
     mesh = jax.make_mesh((2, 4), ("pod", "data"))
     x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
 
-    @partial(shard_map, mesh=mesh, in_specs=P(("pod", "data")),
-             out_specs=P(("pod", "data")), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(("pod", "data")),
+             out_specs=P(("pod", "data")), check_vma=False)
     def hier(v):
         return hierarchical_psum(v, "data", "pod")
 
-    @partial(shard_map, mesh=mesh, in_specs=P(("pod", "data")),
-             out_specs=P(("pod", "data")), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(("pod", "data")),
+             out_specs=P(("pod", "data")), check_vma=False)
     def flat(v):
         return jax.lax.psum(v, ("pod", "data"))
 

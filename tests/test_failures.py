@@ -185,8 +185,7 @@ def test_plan_groups_disabled_specs_with_none(disabled, disp):
     p0 = plan_events(base, n_max=64, w_fpga=16, w_cpu=32)
     p1 = plan_events(mixed, n_max=64, w_fpga=16, w_cpu=32)
     assert p1.n_dispatches == p0.n_dispatches == 1
-    # event statics are (n_max, w_fpga, w_cpu, fstat, arrival_backend)
-    assert all(d.static[3] == FSTAT_OFF for d in p1.dispatches)
+    assert all(d.static[-1] == FSTAT_OFF for d in p1.dispatches)
     p2 = plan_events(mixed + [EventCell(
         disp, arr, 1.0, QFLEET, horizon_s=HORIZON,
         failures=FSPECS["crashy"])], n_max=64, w_fpga=16, w_cpu=32)
@@ -202,7 +201,7 @@ def test_drawn_spec_normalization_consistent(fs):
     cell = EventCell("spork", arr, 1.0, QFLEET, horizon_s=HORIZON,
                      failures=fs)
     plan = plan_events([cell], n_max=64, w_fpga=16, w_cpu=32)
-    is_off = plan.dispatches[0].static[3] == FSTAT_OFF
+    is_off = plan.dispatches[0].static[-1] == FSTAT_OFF
     assert is_off == (fs.normalized() is None)
 
 

@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode on CPU).
+"""Pallas kernels vs pure-jnp oracles (interpret mode on CPU; the TPU
+lowering of each kernel is compiled in tests/test_tpu_compile.py).
 
 Each kernel is swept over shapes/dtypes and checked with assert_allclose
 against its ref.py oracle, plus hypothesis property tests.
@@ -94,28 +95,38 @@ def test_minplus_argmin_tie_breaking(n):
     np.testing.assert_array_equal(np.asarray(got_arg), np.asarray(want_arg))
 
 
-def test_minplus_inside_dp_solver():
-    """solve_dp(use_kernel=True) must agree with the jnp path end-to-end."""
-    from repro.core.dp import solve_dp
+def test_minplus_inside_dp_forward():
+    """Chained through a T-step min-plus forward pass (F_t = min-plus
+    step of F_{t-1} plus a stage cost, as `core.dp` runs it), the kernel
+    must reproduce the jnp transition's values and argmins exactly on
+    integer-valued stage costs."""
+    from repro.core.dp import minplus_step_jnp
     rng = np.random.default_rng(7)
-    W = rng.uniform(0, 30 * DEFAULT_FLEET.T_s, size=24)
-    a = solve_dp(W, DEFAULT_FLEET, energy_weight=1.0, use_kernel=False)
-    b = solve_dp(W, DEFAULT_FLEET, energy_weight=1.0, use_kernel=True)
-    np.testing.assert_allclose(a.objective, b.objective, rtol=1e-5)
-    np.testing.assert_array_equal(a.y_fpga, b.y_fpga)
+    n, T = 200, 12
+    ycs = np.sort(rng.integers(0, 40, (T, n)), axis=1)[:, ::-1]
+    stage = rng.integers(0, 500, (T, n)).astype(np.float32)
+    coeffs = (40.0, 3.0, 2.0, 1.0)
+    fk = fj = jnp.asarray(stage[0])
+    for t in range(1, T):
+        ycp = jnp.asarray(ycs[t - 1].astype(np.float32))
+        ycc = jnp.asarray(ycs[t].astype(np.float32))
+        vk, ak = minplus_pallas(fk, ycp, ycc, jnp.asarray(coeffs),
+                                interpret=True)
+        vj, aj = minplus_step_jnp(fj, ycp, ycc, coeffs)
+        np.testing.assert_array_equal(np.asarray(ak), np.asarray(aj))
+        fk = vk + stage[t]
+        fj = vj + stage[t]
+        np.testing.assert_array_equal(np.asarray(fk), np.asarray(fj))
 
 
-# ----------------------------------------------- minplus (structured)
+# ------------------------------------------ minplus (monotone y_c inputs)
 @pytest.mark.parametrize("n", [1, 8, 100, 128, 130, 257, 1024])
-def test_minplus_structured_kernel_matches_oracles(n):
-    """The scan-based structured kernel must be bit-identical to BOTH the
-    dense jnp oracle and the structured jnp path on monotone y_c inputs
-    (min/argmin combining has no rounding), including non-multiples of
-    the 128 lane block (edge-padded y_c, sentinel-padded F)."""
+def test_minplus_kernel_matches_structured_on_monotone(n):
+    """On the monotone y_c inputs the DP feeds its transitions, the
+    dense kernel must be bit-identical to BOTH the dense jnp oracle and
+    the structured O(N log N) path (integer inputs: no rounding),
+    including non-multiples of the 128 lane block."""
     from repro.core.dp import minplus_step_jnp, minplus_step_structured
-    from repro.kernels.minplus.ops import (
-        minplus_step_structured as kernel_step,
-    )
     rng = np.random.default_rng(n * 13 + 5)
     F = jnp.asarray(rng.integers(-1000, 1000, n).astype(np.float32))
     ycp = jnp.asarray(np.sort(rng.integers(0, 50, n))[::-1]
@@ -125,7 +136,8 @@ def test_minplus_structured_kernel_matches_oracles(n):
     coeffs = (500.0, 5.0, 3.0, 2.0)
     want_v, want_a = minplus_step_jnp(F, ycp, ycc, coeffs)
     ref_v, ref_a = minplus_step_structured(F, ycp, ycc, coeffs)
-    got_v, got_a = kernel_step(F, ycp, ycc, coeffs)
+    got_v, got_a = minplus_pallas(F, ycp, ycc, jnp.asarray(coeffs),
+                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
     np.testing.assert_array_equal(np.asarray(got_a), np.asarray(want_a))
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(ref_v))
@@ -134,11 +146,8 @@ def test_minplus_structured_kernel_matches_oracles(n):
 
 @given(seed=st.integers(0, 10_000), n=st.integers(1, 300))
 @settings(max_examples=15, deadline=None)
-def test_minplus_structured_kernel_property(seed, n):
+def test_minplus_kernel_monotone_property(seed, n):
     from repro.core.dp import minplus_step_jnp
-    from repro.kernels.minplus.ops import (
-        minplus_step_structured as kernel_step,
-    )
     rng = np.random.default_rng(seed)
     F = jnp.asarray(rng.integers(-500, 500, n).astype(np.float32))
     ycp = jnp.asarray(np.sort(rng.integers(0, 8, n))[::-1]
@@ -147,24 +156,24 @@ def test_minplus_structured_kernel_property(seed, n):
                       .astype(np.float32))
     coeffs = tuple(float(x) for x in rng.integers(0, 16, 4))
     want_v, want_a = minplus_step_jnp(F, ycp, ycc, coeffs)
-    got_v, got_a = kernel_step(F, ycp, ycc, coeffs)
+    got_v, got_a = minplus_pallas(F, ycp, ycc, jnp.asarray(coeffs),
+                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
     np.testing.assert_array_equal(np.asarray(got_a), np.asarray(want_a))
 
 
-def test_minplus_structured_kernel_tie_breaking():
-    """First-minimizer rule through the kernel path under heavy ties."""
+def test_minplus_ops_wrapper_tie_breaking():
+    """First-minimizer rule through the public `minplus_step` wrapper
+    (tuple coefficients, platform-decided mode) under heavy ties."""
     from repro.core.dp import minplus_step_jnp
-    from repro.kernels.minplus.ops import (
-        minplus_step_structured as kernel_step,
-    )
+    from repro.kernels.minplus import minplus_step
     n = 130
     rng = np.random.default_rng(n)
     F = jnp.asarray(rng.integers(0, 3, n).astype(np.float32))
     z = jnp.zeros((n,), jnp.float32)
     coeffs = (0.0, 0.0, 0.0, 0.0)
     want_v, want_a = minplus_step_jnp(F, z, z, coeffs)
-    got_v, got_a = kernel_step(F, z, z, coeffs)
+    got_v, got_a = minplus_step(F, z, z, coeffs)
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
     np.testing.assert_array_equal(np.asarray(got_a), np.asarray(want_a))
 

@@ -2,7 +2,7 @@
 convergence against the §5.1 grid search.
 
 The relaxation (`repro.policies.tune.relaxed_cost`) is dtype-agnostic
-so the derivative checks run in float64 (`jax.experimental.enable_x64`)
+so the derivative checks run in float64 (`jax.enable_x64`)
 where central differences are trustworthy to ~1e-6: the analytic
 gradient through the whole `lax.scan` must match central FD on every
 tuned parameter (headroom, forecast gain, utilization target) at
@@ -39,7 +39,7 @@ def test_grad_matches_central_fd_all_params(theta0):
     """Analytic `jax.grad` vs central finite differences on every tuned
     parameter, in float64 where FD error ~h^2 is far below tolerance."""
     tr = _trace()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = tune.make_spec(tr.counts, tr.request_size_s, DEFAULT_FLEET,
                               dtype=jnp.float64)
         theta = jnp.asarray(theta0, jnp.float64)
@@ -63,7 +63,7 @@ def test_grad_is_informative_on_every_param():
     surrogate (the reason the relaxation exists — the integer dynamics
     have zero gradient almost everywhere)."""
     tr = _trace()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = tune.make_spec(tr.counts, tr.request_size_s, DEFAULT_FLEET,
                               dtype=jnp.float64)
         g = np.asarray(tune.relaxed_grad(

@@ -105,3 +105,20 @@ def test_underallocation_bias_when_spinup_dominates():
             p.record_lifetime(lvl, 100 * fleet.T_s)
     pred_long = p.predict(n_prev=2, n_curr=2)
     assert pred_long >= pred_short
+
+
+@pytest.mark.parametrize("n", [128, 200])
+def test_prefix_sum_is_independent_of_batch_width(n):
+    """A cell's prefix sums must not change with the number of cells
+    vmapped beside it: `MeshBackend` runs each device's lane shard of a
+    chunk and is held bit-identical to `LocalBackend`."""
+    import jax
+    from repro.core.predictor import _prefix_sum
+    x = np.random.default_rng(n).uniform(0.0, 1.0, (32, n)).astype(np.float32)
+    scan = jax.jit(jax.vmap(_prefix_sum))
+    full = np.asarray(scan(jnp.asarray(x)))
+    for width in (1, 4, 8, 16):
+        np.testing.assert_array_equal(np.asarray(scan(jnp.asarray(x[:width]))),
+                                      full[:width])
+    np.testing.assert_allclose(full, np.cumsum(x.astype(np.float64), axis=1),
+                               rtol=1e-5)
