@@ -806,6 +806,10 @@ class EventCell:
                 f"{np.shape(self.seed)}")
 
 
+# Cost: O(intervals x arrivals) per cell (``idx == k`` over every arrival
+# for each of the K + 1 buckets), and `plan_events` runs it once per cell,
+# so a stream shared by several dispatchers is bucketed once per
+# dispatcher. Its share of planning is the ``repro.plan.entries`` span.
 def _entries(arr: np.ndarray, interval_s: float, horizon: float,
              payload: np.ndarray | None = None) -> list[tuple]:
     """Flat entry stream for one cell: fixed-width arrival blocks with

@@ -49,6 +49,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.policies import RateParams
 from repro.sim import events_batched, ratesim
@@ -111,6 +112,14 @@ def _fleet_args(d: ChunkDispatch) -> tuple:
             jnp.asarray(a["adm_quota"]))
 
 
+def _transfer(d: ChunkDispatch) -> tuple:
+    """The dispatch's host arrays put on the device, as its program's
+    traced arguments."""
+    with TraceAnnotation("repro.exec.transfer"):
+        return {"rate": _rate_args,
+                "fleet": _fleet_args}.get(d.kind, _event_args)(d)
+
+
 class Backend:
     """One way of running a plan's dispatches. Subclasses implement
     `run(dispatch)` (returning the core's output pytree) and
@@ -140,13 +149,13 @@ class LocalBackend(Backend):
     name = "local"
 
     def run(self, d: ChunkDispatch):
+        args = _transfer(d)
         if d.kind == "rate":
-            return ratesim._simulate_cells(*d.static, *_rate_args(d))
+            return ratesim._simulate_cells(*d.static, *args)
         if d.kind == "fleet":
             from repro.fleet import engine as fleet_engine
-            return fleet_engine._simulate_fleet_cells(*d.static,
-                                                      *_fleet_args(d))
-        return events_batched._simulate_cells(*d.static, *_event_args(d))
+            return fleet_engine._simulate_fleet_cells(*d.static, *args)
+        return events_batched._simulate_cells(*d.static, *args)
 
 
 class MeshBackend(Backend):
@@ -203,9 +212,7 @@ class MeshBackend(Backend):
 
     def run(self, d: ChunkDispatch):
         fn = self._fn(d.kind, d.static, self.devices_for(d))
-        args = {"rate": _rate_args,
-                "fleet": _fleet_args}.get(d.kind, _event_args)(d)
-        return fn(*args)
+        return fn(*_transfer(d))
 
 
 _BACKENDS = {"local": LocalBackend, "mesh": MeshBackend}
@@ -243,21 +250,29 @@ def execute(plan: SweepPlan, backend: str | Backend | None = None, *,
     ``retry`` is a `repro.sim.harness.RetryPolicy` (bounded retry +
     backoff, per-chunk wall timeout, mesh->local degradation); and the
     invariant guards validate every result by default (``validate=None``
-    reads the ``REPRO_SKIP_INVARIANTS`` opt-out, True/False force)."""
+    reads the ``REPRO_SKIP_INVARIANTS`` opt-out, True/False force).
+
+    The result's ``meta`` also carries the plan's ``meta`` (its
+    ``plan_id`` and size counters, see `repro.sim.plan.SweepPlan`)."""
     from repro.sim.harness import (ResilientRunner, check_sweep_result,
                                    invariants_enabled)
-    backend = get_backend(backend)
-    runner = ResilientRunner(backend, checkpoint_dir=checkpoint_dir,
-                             retry=retry)
-    if plan.kind == "rate":
-        res = _execute_rate(plan, backend, runner)
-    elif plan.kind == "fleet":
-        res = _execute_fleet(plan, backend, runner)
-    else:
-        res = _execute_event(plan, backend, runner)
-    res.meta.update(runner.meta())
-    if invariants_enabled() if validate is None else validate:
-        check_sweep_result(res)
+    plan_id = plan.meta.get("plan_id", 0)
+    with TraceAnnotation("repro.exec", plan_id=plan_id,
+                         dispatches=plan.n_dispatches):
+        backend = get_backend(backend)
+        runner = ResilientRunner(backend, checkpoint_dir=checkpoint_dir,
+                                 retry=retry, plan_id=plan_id)
+        if plan.kind == "rate":
+            res = _execute_rate(plan, backend, runner)
+        elif plan.kind == "fleet":
+            res = _execute_fleet(plan, backend, runner)
+        else:
+            res = _execute_event(plan, backend, runner)
+        res.meta.update(plan.meta)
+        res.meta.update(runner.meta())
+        if invariants_enabled() if validate is None else validate:
+            with TraceAnnotation("repro.harness.guards"):
+                check_sweep_result(res)
     return res
 
 
@@ -268,9 +283,10 @@ def _execute_rate(plan: SweepPlan, backend: Backend, runner) -> SweepResult:
     for d in plan.dispatches:
         acc = runner.run(d)
         devs.append(backend.devices_for(d))
-        dest = list(d.cell_idx)
-        for leaf, out in zip(acc, leaves):
-            out[dest] = np.asarray(leaf)[:d.n_real]
+        with TraceAnnotation("repro.exec.scatter"):
+            dest = list(d.cell_idx)
+            for leaf, out in zip(acc, leaves):
+                out[dest] = np.asarray(leaf)[:d.n_real]
     return SweepResult(plan.cells, Accum(*leaves), plan.work, plan.requests,
                        n_dispatches=plan.n_dispatches, backend=backend.name,
                        n_devices=backend.n_devices, dispatch_devices=devs)
@@ -283,28 +299,29 @@ def _execute_event(plan: SweepPlan, backend: Backend,
     for d in plan.dispatches:
         acc, fail, over = runner.run(d)
         devs.append(backend.devices_for(d))
-        acc_np = [np.asarray(leaf) for leaf in acc]
-        fail_np = [np.asarray(leaf) for leaf in fail]
-        over_np = np.asarray(over)
-        for r, i in enumerate(d.cell_idx):
-            cell = plan.cells[i]
-            n_req = len(cell.arrival_times)
-            tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc_np]),
-                                  n_req * cell.size_s, n_req)
-            fl = events_batched.FailAcc(*[leaf[r] for leaf in fail_np])
-            # resilience counters + the oracle's finalize composition:
-            # wasted spin-up energy joins energy_j, stillborn occupancy
-            # joins cost_usd (all exactly zero when the axis is off)
-            tot.retries = int(fl.retries)
-            tot.failed_spinups = int(fl.failed_spins)
-            tot.crashes = int(fl.crashes)
-            tot.recovered_requests = int(fl.recovered)
-            tot.failure_misses = int(fl.fail_misses)
-            tot.wasted_spinup_j = float(fl.wasted_j)
-            tot.energy_j += float(fl.wasted_j)
-            tot.cost_usd += float(fl.extra_cost)
-            tot.breakdown["slot_overflow"] = int(over_np[r])
-            out[i] = tot
+        with TraceAnnotation("repro.exec.scatter"):
+            acc_np = [np.asarray(leaf) for leaf in acc]
+            fail_np = [np.asarray(leaf) for leaf in fail]
+            over_np = np.asarray(over)
+            for r, i in enumerate(d.cell_idx):
+                cell = plan.cells[i]
+                n_req = len(cell.arrival_times)
+                tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc_np]),
+                                      n_req * cell.size_s, n_req)
+                fl = events_batched.FailAcc(*[leaf[r] for leaf in fail_np])
+                # resilience counters + the oracle's finalize composition:
+                # wasted spin-up energy joins energy_j, stillborn occupancy
+                # joins cost_usd (all exactly zero when the axis is off)
+                tot.retries = int(fl.retries)
+                tot.failed_spinups = int(fl.failed_spins)
+                tot.crashes = int(fl.crashes)
+                tot.recovered_requests = int(fl.recovered)
+                tot.failure_misses = int(fl.fail_misses)
+                tot.wasted_spinup_j = float(fl.wasted_j)
+                tot.energy_j += float(fl.wasted_j)
+                tot.cost_usd += float(fl.extra_cost)
+                tot.breakdown["slot_overflow"] = int(over_np[r])
+                out[i] = tot
     return EventSweepResult(plan.cells, out, n_dispatches=plan.n_dispatches,
                             backend=backend.name,
                             n_devices=backend.n_devices,
@@ -328,45 +345,46 @@ def _execute_fleet(plan: SweepPlan, backend: Backend,
     for d in plan.dispatches:
         acc, fail, over, fa = runner.run(d)
         devs.append(backend.devices_for(d))
-        acc_np = [np.asarray(leaf) for leaf in acc]
-        fail_np = [np.asarray(leaf) for leaf in fail]
-        over_np = np.asarray(over)
-        fa_np = [np.asarray(leaf) for leaf in fa]
-        for r, i in enumerate(d.cell_idx):
-            cell = plan.cells[i]
-            rs = resolve_fleet_cell(cell)       # lru-cached
-            n = rs.n_tenants
-            offered, admitted, shed, missed, work_f, work_c = (
-                leaf[r, :n] for leaf in fa_np)
-            n_adm = int(admitted.sum())
-            work = float((admitted.astype(np.float64) * rs.sizes).sum())
-            tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc_np]),
-                                  work, n_adm)
-            fl = events_batched.FailAcc(*[leaf[r] for leaf in fail_np])
-            tot.retries = int(fl.retries)
-            tot.failed_spinups = int(fl.failed_spins)
-            tot.crashes = int(fl.crashes)
-            tot.recovered_requests = int(fl.recovered)
-            tot.failure_misses = int(fl.fail_misses)
-            tot.wasted_spinup_j = float(fl.wasted_j)
-            tot.energy_j += float(fl.wasted_j)
-            tot.cost_usd += float(fl.extra_cost)
-            # per-tenant sums ARE the fleet-level numbers (each arrival
-            # increments exactly one tenant's counter and the matching
-            # shared counter, so these agree with the Accum up to f32)
-            tot.deadline_misses = int(missed.sum())
-            tot.work_on_fpga_cpu_s = float(
-                work_f.astype(np.float64).sum())
-            tot.work_on_cpu_cpu_s = float(
-                work_c.astype(np.float64).sum())
-            tot.breakdown["slot_overflow"] = int(over_np[r])
-            tot.breakdown["offered_requests"] = int(offered.sum())
-            tot.breakdown["shed_requests"] = int(shed.sum())
-            out[i] = tot
-            tenants[i] = attribute_tenants(
-                tot, rs.weights, rs.sizes, offered, admitted, shed,
-                missed, work_f.astype(np.float64),
-                work_c.astype(np.float64))
+        with TraceAnnotation("repro.exec.scatter"):
+            acc_np = [np.asarray(leaf) for leaf in acc]
+            fail_np = [np.asarray(leaf) for leaf in fail]
+            over_np = np.asarray(over)
+            fa_np = [np.asarray(leaf) for leaf in fa]
+            for r, i in enumerate(d.cell_idx):
+                cell = plan.cells[i]
+                rs = resolve_fleet_cell(cell)       # lru-cached
+                n = rs.n_tenants
+                offered, admitted, shed, missed, work_f, work_c = (
+                    leaf[r, :n] for leaf in fa_np)
+                n_adm = int(admitted.sum())
+                work = float((admitted.astype(np.float64) * rs.sizes).sum())
+                tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc_np]),
+                                      work, n_adm)
+                fl = events_batched.FailAcc(*[leaf[r] for leaf in fail_np])
+                tot.retries = int(fl.retries)
+                tot.failed_spinups = int(fl.failed_spins)
+                tot.crashes = int(fl.crashes)
+                tot.recovered_requests = int(fl.recovered)
+                tot.failure_misses = int(fl.fail_misses)
+                tot.wasted_spinup_j = float(fl.wasted_j)
+                tot.energy_j += float(fl.wasted_j)
+                tot.cost_usd += float(fl.extra_cost)
+                # per-tenant sums ARE the fleet-level numbers (each arrival
+                # increments exactly one tenant's counter and the matching
+                # shared counter, so these agree with the Accum up to f32)
+                tot.deadline_misses = int(missed.sum())
+                tot.work_on_fpga_cpu_s = float(
+                    work_f.astype(np.float64).sum())
+                tot.work_on_cpu_cpu_s = float(
+                    work_c.astype(np.float64).sum())
+                tot.breakdown["slot_overflow"] = int(over_np[r])
+                tot.breakdown["offered_requests"] = int(offered.sum())
+                tot.breakdown["shed_requests"] = int(shed.sum())
+                out[i] = tot
+                tenants[i] = attribute_tenants(
+                    tot, rs.weights, rs.sizes, offered, admitted, shed,
+                    missed, work_f.astype(np.float64),
+                    work_c.astype(np.float64))
     return FleetSweepResult(plan.cells, out, tenants,
                             n_dispatches=plan.n_dispatches,
                             backend=backend.name,

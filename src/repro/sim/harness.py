@@ -59,6 +59,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint.manager import ChunkStore
 from repro.core.metrics import RunTotals
@@ -438,8 +439,9 @@ class ResilientRunner:
     `meta()` is attached to the `SweepResult`/`EventSweepResult`."""
 
     def __init__(self, backend, checkpoint_dir=None,
-                 retry: RetryPolicy | None = None):
+                 retry: RetryPolicy | None = None, plan_id: int = 0):
         self.backend = backend
+        self.plan_id = plan_id       # the plan's number, on every span
         self.retry = retry or DEFAULT_RETRY
         self.store = (ChunkStore(checkpoint_dir)
                       if checkpoint_dir is not None else None)
@@ -464,32 +466,47 @@ class ResilientRunner:
     # -- the one entry point the exec scatter loops call per dispatch --
     def run(self, dispatch):
         self._chunk_i += 1
-        key = (chunk_fingerprint(dispatch, self.backend.name)
-               if self.store is not None else None)
-        if key is not None and self.store.has(key):
-            self.restored_chunks += 1
-            return _reassemble_output(dispatch.kind, self.store.load(key))
-        out = self._run_live(dispatch)
-        leaves = _flatten_output(dispatch.kind, out)
-        if key is not None:
-            self.store.save(key, leaves,
-                            metadata={"kind": dispatch.kind,
-                                      "backend": self.backend.name,
-                                      "chunk": dispatch.chunk,
-                                      "n_real": dispatch.n_real,
-                                      "salt": CODE_SALT})
-        self.executed_chunks += 1
-        if (self._kill_after is not None
-                and self.executed_chunks >= self._kill_after):
-            # test hook: die the hard way, mid-sweep, after persisting
-            os.kill(os.getpid(), signal.SIGKILL)
-        return _reassemble_output(dispatch.kind, leaves)
+        times = dispatch.arrays.get("times")
+        width = {} if times is None else {"E": times.shape[1]}
+        with TraceAnnotation("repro.exec.dispatch", plan_id=self.plan_id,
+                             chunk=self._chunk_i, rows=dispatch.chunk,
+                             **width):
+            key = None
+            if self.store is not None:
+                with TraceAnnotation("repro.harness.checkpoint"):
+                    key = chunk_fingerprint(dispatch, self.backend.name)
+                    saved = self.store.load(key) if self.store.has(key) \
+                        else None
+                if saved is not None:
+                    self.restored_chunks += 1
+                    return _reassemble_output(dispatch.kind, saved)
+            out = self._run_live(dispatch)
+            with TraceAnnotation("repro.exec.fetch"):
+                leaves = _flatten_output(dispatch.kind, out)
+            if key is not None:
+                with TraceAnnotation("repro.harness.checkpoint"):
+                    self.store.save(key, leaves,
+                                    metadata={"kind": dispatch.kind,
+                                              "backend": self.backend.name,
+                                              "chunk": dispatch.chunk,
+                                              "n_real": dispatch.n_real,
+                                              "salt": CODE_SALT})
+            self.executed_chunks += 1
+            if (self._kill_after is not None
+                    and self.executed_chunks >= self._kill_after):
+                # test hook: die the hard way, mid-sweep, after persisting
+                os.kill(os.getpid(), signal.SIGKILL)
+            return _reassemble_output(dispatch.kind, leaves)
 
     def _attempt(self, backend, dispatch):
         """One dispatch attempt, blocked to completion so the timeout
-        covers compile + compute, not just program launch."""
+        covers compile + compute, not just program launch. Under a
+        timeout it runs on another thread: ``plan_id`` and ``chunk`` tie
+        its span to the dispatch's."""
         import jax
-        return jax.block_until_ready(backend.run(dispatch))
+        with TraceAnnotation("repro.exec.run", plan_id=self.plan_id,
+                             chunk=self._chunk_i):
+            return jax.block_until_ready(backend.run(dispatch))
 
     def _run_live(self, dispatch):
         r = self.retry

@@ -41,11 +41,13 @@ Invariants (enforced by tests/test_plan.py):
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.metrics import Report, RunTotals, report
 from repro.core.workers import FleetParams
@@ -75,6 +77,11 @@ _N_MAX_CAP = 512
 # `FleetScalars.A_f_s`). Their cells are regrouped under one canonical
 # static key so every spin-up value shares a compiled program.
 _CANON_INTERVAL = 10
+
+# Per-process plan number, kept in `SweepPlan.meta["plan_id"]` and on the
+# `repro.plan` / `repro.exec` spans, so a trace ties a grid's planning to
+# its execution even when the caller plans and executes separately.
+_PLAN_IDS = itertools.count(1)
 
 
 @functools.lru_cache(maxsize=256)
@@ -168,7 +175,14 @@ class SweepPlan:
     """An explicit sweep execution plan: resolved cells (in caller
     order) plus the dispatch list any `repro.sim.exec` backend can run.
     ``work``/``requests`` are per-cell totals precomputed during
-    planning (rate plans only; event totals derive from the cells)."""
+    planning (rate plans only; event totals derive from the cells).
+
+    ``meta`` holds the plan's ``plan_id`` and its size counters, sums
+    over dispatches or cells that `repro.sim.exec.execute` copies into
+    the result's ``meta``: ``cells`` (real rows), ``rows`` (chunk),
+    ``h2d_bytes`` (the dispatch arrays), and for event and fleet plans
+    ``row_entries`` (real rows x E), ``entries_scanned`` (chunk x E),
+    ``entries`` (real entries) and ``arrivals`` (real arrivals)."""
 
     kind: str                       # "rate" | "event"
     cells: list
@@ -183,6 +197,43 @@ class SweepPlan:
         return len(self.dispatches)
 
 
+def _counters(dispatches: list[ChunkDispatch], entries: dict | None = None,
+              arrivals: int = 0) -> dict:
+    """The plan's size counters (`SweepPlan.meta`). With ``entries``
+    (each cell's entry stream: event and fleet plans), the share of
+    arrival slots the dispatches fill splits into three factors:
+    ``row_entries / entries_scanned`` (chunk rounding), ``entries /
+    row_entries`` (E rounding), ``arrivals / (BLOCK * entries)``
+    (part-full blocks)."""
+    out = {"cells": sum(d.n_real for d in dispatches),
+           "rows": sum(d.chunk for d in dispatches),
+           "h2d_bytes": sum(a.nbytes for d in dispatches
+                            for a in d.arrays.values())}
+    if entries is not None:
+        widths = [(d, d.arrays["times"].shape[1]) for d in dispatches]
+        out.update(row_entries=sum(d.n_real * E for d, E in widths),
+                   entries_scanned=sum(d.chunk * E for d, E in widths),
+                   entries=sum(len(e) for e in entries.values()),
+                   arrivals=arrivals)
+    return out
+
+
+def _traced_plan(plan_fn):
+    """Run a planner inside the ``repro.plan`` span and number its plan
+    (``meta["plan_id"]``)."""
+    @functools.wraps(plan_fn)
+    def planner(cells: Iterable, *args, **kwargs) -> SweepPlan:
+        cells = list(cells)
+        plan_id = next(_PLAN_IDS)
+        with TraceAnnotation("repro.plan", plan_id=plan_id,
+                             cells=len(cells)):
+            plan = plan_fn(cells, *args, **kwargs)
+        plan.meta = {"plan_id": plan_id, **plan.meta}
+        return plan
+    return planner
+
+
+@_traced_plan
 def plan_sweep(cells: Iterable, n_max: int | None = None) -> SweepPlan:
     """Plan a rate-simulator sweep: one `ChunkDispatch` per (policy,
     interval, spin-up, horizon) group chunk, arrays laid out exactly as
@@ -195,84 +246,90 @@ def plan_sweep(cells: Iterable, n_max: int | None = None) -> SweepPlan:
     ``failures`` is cleared (the plan's cells record what was actually
     simulated; re-planning them will not degrade twice). The DES engines
     are the exact path — docs/architecture.md §Failure model."""
+    with TraceAnnotation("repro.plan.resolve"):
+        cells = resolve_scenarios(cells)
     cells = [
         c if getattr(c, "failures", None) is None
         or c.failures.normalized() is None
         else replace(c, fleet=c.failures.degrade_fleet(c.fleet),
                      failures=None)
-        for c in resolve_scenarios(cells)]
-    groups: dict[tuple, list[int]] = {}
-    for i, c in enumerate(cells):
-        # the policy OBJECT (frozen dataclass: hashable, stable repr) is
-        # the group key and rides through `ChunkDispatch.static` — its
-        # static structure picks the compiled program, its traced
-        # parameters (headroom/level/gain) travel in the arrays
-        pol = get_rate_policy(c.policy)
-        interval_s = max(int(round(c.fleet.T_s)), 1)
-        spin_up_s = max(int(round(c.fleet.fpga.spin_up_s)), 1)
-        horizon = (len(c.counts) // interval_s) * interval_s
-        if pol.latency_free and horizon % _CANON_INTERVAL == 0:
-            interval_s = spin_up_s = _CANON_INTERVAL
-        groups.setdefault((pol, interval_s, spin_up_s, horizon,
-                           n_max or _N_MAX_CAP), []).append(i)
+        for c in cells]
+    with TraceAnnotation("repro.plan.pack"):
+        groups: dict[tuple, list[int]] = {}
+        for i, c in enumerate(cells):
+            # the policy OBJECT (frozen dataclass: hashable, stable repr) is
+            # the group key and rides through `ChunkDispatch.static` — its
+            # static structure picks the compiled program, its traced
+            # parameters (headroom/level/gain) travel in the arrays
+            pol = get_rate_policy(c.policy)
+            interval_s = max(int(round(c.fleet.T_s)), 1)
+            spin_up_s = max(int(round(c.fleet.fpga.spin_up_s)), 1)
+            horizon = (len(c.counts) // interval_s) * interval_s
+            if pol.latency_free and horizon % _CANON_INTERVAL == 0:
+                interval_s = spin_up_s = _CANON_INTERVAL
+            groups.setdefault((pol, interval_s, spin_up_s, horizon,
+                               n_max or _N_MAX_CAP), []).append(i)
 
-    n = len(cells)
-    work = np.zeros((n,), np.float64)
-    requests = np.zeros((n,), np.int64)
-    dispatches: list[ChunkDispatch] = []
+        n = len(cells)
+        work = np.zeros((n,), np.float64)
+        requests = np.zeros((n,), np.int64)
+        dispatches: list[ChunkDispatch] = []
 
-    for (pol, interval_s, spin_up_s, horizon, nm), idxs in groups.items():
-        group = [cells[i] for i in idxs]
-        counts = np.stack([np.asarray(c.counts[:horizon], np.int32)
-                           for c in group])
-        sizes = np.array([c.size_s for c in group], np.float32)
-        ew = np.array([c.energy_weight for c in group], np.float32)
-        hr = np.array([c.headroom for c in group], np.int32)
-        gain = np.array([getattr(c, "forecast_gain", 1.0) for c in group],
-                        np.float32)
-        scal = np.array([_fleet_scalars_np(c.fleet) for c in group],
-                        np.float32)     # (C, len(FleetScalars._fields))
-        if pol.name == "fpga_static":
-            levels = np.array(
-                [static_level_for(c.counts[:horizon], c.size_s, c.fleet, nm)
-                 for c in group], np.int32)
-        else:
-            levels = np.zeros((len(group),), np.int32)
-
-        work[idxs] = counts.sum(1, dtype=np.float64) * sizes
-        requests[idxs] = counts.sum(1, dtype=np.int64)
-
-        start = 0
-        while start < len(group):
-            left = len(group) - start
-            # Predictor policies carry O(n_max^2) histogram state per
-            # cell, so they always use the small shape; cheap policies
-            # jump to the big shape for expanded grids (headroom tuning).
-            if pol.uses_predictor or left <= CHUNK:
-                chunk = CHUNK
+        for (pol, interval_s, spin_up_s, horizon, nm), idxs in groups.items():
+            group = [cells[i] for i in idxs]
+            counts = np.stack([np.asarray(c.counts[:horizon], np.int32)
+                               for c in group])
+            sizes = np.array([c.size_s for c in group], np.float32)
+            ew = np.array([c.energy_weight for c in group], np.float32)
+            hr = np.array([c.headroom for c in group], np.int32)
+            gain = np.array([getattr(c, "forecast_gain", 1.0) for c in group],
+                            np.float32)
+            scal = np.array([_fleet_scalars_np(c.fleet) for c in group],
+                            np.float32)     # (C, len(FleetScalars._fields))
+            if pol.name == "fpga_static":
+                levels = np.array(
+                    [static_level_for(c.counts[:horizon], c.size_s,
+                                      c.fleet, nm)
+                     for c in group], np.int32)
             else:
-                chunk = CHUNK_BIG
-            sl = slice(start, min(start + chunk, len(group)))
-            start += chunk
-            arrays = {
-                "counts": _pad(counts[sl], chunk),
-                "sizes": _pad(sizes[sl], chunk),
-                "scalars": _pad(scal[sl], chunk),
-                "energy_weight": _pad(ew[sl], chunk),
-                "headroom": _pad(hr[sl], chunk),
-                "levels": _pad(levels[sl], chunk),
-                "gain": _pad(gain[sl], chunk),
-            }
-            dispatches.append(ChunkDispatch(
-                kind="rate",
-                static=(pol, interval_s, spin_up_s, nm, horizon),
-                arrays=arrays, cell_idx=tuple(idxs[sl.start:sl.stop]),
-                chunk=chunk))
+                levels = np.zeros((len(group),), np.int32)
+
+            work[idxs] = counts.sum(1, dtype=np.float64) * sizes
+            requests[idxs] = counts.sum(1, dtype=np.int64)
+
+            start = 0
+            while start < len(group):
+                left = len(group) - start
+                # Predictor policies carry O(n_max^2) histogram state per
+                # cell, so they always use the small shape; cheap policies
+                # jump to the big shape for expanded grids (headroom tuning).
+                if pol.uses_predictor or left <= CHUNK:
+                    chunk = CHUNK
+                else:
+                    chunk = CHUNK_BIG
+                sl = slice(start, min(start + chunk, len(group)))
+                start += chunk
+                arrays = {
+                    "counts": _pad(counts[sl], chunk),
+                    "sizes": _pad(sizes[sl], chunk),
+                    "scalars": _pad(scal[sl], chunk),
+                    "energy_weight": _pad(ew[sl], chunk),
+                    "headroom": _pad(hr[sl], chunk),
+                    "levels": _pad(levels[sl], chunk),
+                    "gain": _pad(gain[sl], chunk),
+                }
+                dispatches.append(ChunkDispatch(
+                    kind="rate",
+                    static=(pol, interval_s, spin_up_s, nm, horizon),
+                    arrays=arrays, cell_idx=tuple(idxs[sl.start:sl.stop]),
+                    chunk=chunk))
 
     return SweepPlan("rate", cells, dispatches, n_max or _N_MAX_CAP,
-                     work=work, requests=requests)
+                     work=work, requests=requests,
+                     meta=_counters(dispatches))
 
 
+@_traced_plan
 def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                 w_cpu: int = 64, resolve: bool = True) -> SweepPlan:
     """Plan a DES sweep: cells grouped by padded entry-stream length,
@@ -288,70 +345,78 @@ def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     At benchmark scale that is megabytes; callers planning very long
     streams x many chunks should slab their cell lists into multiple
     plans."""
-    cells = resolve_scenarios(cells) if resolve else list(cells)
-    codes = {}
-    for i, cl in enumerate(cells):
-        codes[i] = get_dispatch_policy(cl.dispatcher).code
-        if cl.arrival_times is None or cl.size_s is None:
-            raise ValueError(
-                "EventCell without explicit demand (arrival_times + "
-                "size_s); scenario-bearing cells must go through "
-                "repro.sim.sweep.sweep_events, which resolves them")
-    entries: dict[int, list] = {}
-    groups: dict[tuple, list[int]] = {}
-    for i, cl in enumerate(cells):
-        arr = np.asarray(cl.arrival_times, np.float64)
-        horizon = float(cl.horizon_s if cl.horizon_s is not None
-                        else (arr[-1] + 1.0 if len(arr) else 1.0))
-        entries[i] = _entries(arr, cl.fleet.T_s, horizon)
-        n_e = len(entries[i])
-        # pow2 up to 256 entries, then multiples of 256: every padded
-        # entry costs a full BLOCK of inert arrival slots, so tight
-        # padding beats shape reuse once streams are long.
-        E = (_pad_pow2(n_e, lo=4) if n_e <= 256
-             else 256 * int(math.ceil(n_e / 256)))
-        # the failure axis's static part joins the group key: disabled
-        # cells compile (and stay on) the pristine pre-failure program
-        groups.setdefault((E, fail_static(cl.failures)), []).append(i)
+    if resolve:
+        with TraceAnnotation("repro.plan.resolve"):
+            cells = resolve_scenarios(cells)
+    with TraceAnnotation("repro.plan.entries"):
+        codes = {}
+        for i, cl in enumerate(cells):
+            codes[i] = get_dispatch_policy(cl.dispatcher).code
+            if cl.arrival_times is None or cl.size_s is None:
+                raise ValueError(
+                    "EventCell without explicit demand (arrival_times + "
+                    "size_s); scenario-bearing cells must go through "
+                    "repro.sim.sweep.sweep_events, which resolves them")
+        entries: dict[int, list] = {}
+        groups: dict[tuple, list[int]] = {}
+        arrivals = 0
+        for i, cl in enumerate(cells):
+            arr = np.asarray(cl.arrival_times, np.float64)
+            arrivals += len(arr)
+            horizon = float(cl.horizon_s if cl.horizon_s is not None
+                            else (arr[-1] + 1.0 if len(arr) else 1.0))
+            entries[i] = _entries(arr, cl.fleet.T_s, horizon)
+            n_e = len(entries[i])
+            # pow2 up to 256 entries, then multiples of 256: every padded
+            # entry costs a full BLOCK of inert arrival slots, so tight
+            # padding beats shape reuse once streams are long.
+            E = (_pad_pow2(n_e, lo=4) if n_e <= 256
+                 else 256 * int(math.ceil(n_e / 256)))
+            # the failure axis's static part joins the group key: disabled
+            # cells compile (and stay on) the pristine pre-failure program
+            groups.setdefault((E, fail_static(cl.failures)), []).append(i)
 
-    dispatches: list[ChunkDispatch] = []
-    for (E, fstat), idxs in groups.items():
-        chunk = _pad_pow2(len(idxs), lo=4, hi=EV_CHUNK_MAX)
-        start = 0
-        while start < len(idxs):
-            sl = idxs[start:start + chunk]
-            start += chunk
-            pad = sl + [sl[0]] * (chunk - len(sl))
-            times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
-            tick_t = np.zeros((len(pad), E), np.float32)
-            is_tick = np.zeros((len(pad), E), bool)
-            for r, i in enumerate(pad):
-                for e, (row, tick) in enumerate(entries[i]):
-                    times[r, e, :len(row)] = row
-                    if tick is not None:
-                        tick_t[r, e] = tick
-                        is_tick[r, e] = True
-            arrays = {
-                "scalars": np.array([_scalars(cells[i])[:-2] for i in pad],
-                                    np.float32),
-                "fail_seed": np.array(
-                    [(cells[i].failures.seed
-                      if cells[i].failures is not None else 0)
-                     for i in pad], np.uint32),
-                "max_fpgas": np.array([cells[i].fleet.max_fpgas
-                                       for i in pad], np.int32),
-                "allocate": np.array([cells[i].allocate_fpgas
-                                      for i in pad], bool),
-                "codes": np.array([codes[i] for i in pad], np.int32),
-                "times": times, "tick_t": tick_t, "is_tick": is_tick,
-            }
-            dispatches.append(ChunkDispatch(
-                kind="event", static=(n_max, w_fpga, w_cpu, fstat),
-                arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
+    with TraceAnnotation("repro.plan.pack"):
+        dispatches: list[ChunkDispatch] = []
+        for (E, fstat), idxs in groups.items():
+            chunk = _pad_pow2(len(idxs), lo=4, hi=EV_CHUNK_MAX)
+            start = 0
+            while start < len(idxs):
+                sl = idxs[start:start + chunk]
+                start += chunk
+                pad = sl + [sl[0]] * (chunk - len(sl))
+                times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
+                tick_t = np.zeros((len(pad), E), np.float32)
+                is_tick = np.zeros((len(pad), E), bool)
+                for r, i in enumerate(pad):
+                    for e, (row, tick) in enumerate(entries[i]):
+                        times[r, e, :len(row)] = row
+                        if tick is not None:
+                            tick_t[r, e] = tick
+                            is_tick[r, e] = True
+                arrays = {
+                    "scalars": np.array([_scalars(cells[i])[:-2] for i in pad],
+                                        np.float32),
+                    "fail_seed": np.array(
+                        [(cells[i].failures.seed
+                          if cells[i].failures is not None else 0)
+                         for i in pad], np.uint32),
+                    "max_fpgas": np.array([cells[i].fleet.max_fpgas
+                                           for i in pad], np.int32),
+                    "allocate": np.array([cells[i].allocate_fpgas
+                                          for i in pad], bool),
+                    "codes": np.array([codes[i] for i in pad], np.int32),
+                    "times": times, "tick_t": tick_t, "is_tick": is_tick,
+                }
+                dispatches.append(ChunkDispatch(
+                    kind="event", static=(n_max, w_fpga, w_cpu, fstat),
+                    arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
 
-    return SweepPlan("event", cells, dispatches, n_max)
+    return SweepPlan("event", cells, dispatches, n_max,
+                     meta=_counters(dispatches, entries, arrivals))
 
 
+@_traced_plan
 def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                w_cpu: int = 64) -> SweepPlan:
     """Plan a multi-tenant fleet sweep (`repro.fleet.FleetCell` cells):
@@ -370,29 +435,33 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     from repro.fleet.specs import FleetCell, resolve_fleet_cell
     from repro.sim.events_batched import EventCell
 
-    cells = list(cells)
-    entries: dict[int, list] = {}
-    resolved: dict[int, Any] = {}
-    groups: dict[tuple, list[int]] = {}
-    codes, acodes = {}, {}
     from repro.policies import get_admission_policy
-    for i, cl in enumerate(cells):
-        if not isinstance(cl, FleetCell):
-            raise TypeError(
-                f"plan_fleet needs repro.fleet.FleetCell cells, got "
-                f"{type(cl).__name__}")
-        rs = resolve_fleet_cell(cl)
-        resolved[i] = rs
-        codes[i] = get_dispatch_policy(cl.dispatcher).code
-        acodes[i] = get_admission_policy(cl.admission).code
-        entries[i] = _entries(rs.times, cl.fleet.T_s, rs.horizon_s,
-                              payload=rs.tids)
-        n_e = len(entries[i])
-        E = (_pad_pow2(n_e, lo=4) if n_e <= 256
-             else 256 * int(math.ceil(n_e / 256)))
-        N_pad = _pad_pow2(rs.n_tenants, lo=4)
-        groups.setdefault((E, N_pad, fail_static(rs.failures)),
-                          []).append(i)
+    resolved: dict[int, Any] = {}
+    with TraceAnnotation("repro.plan.resolve"):
+        for i, cl in enumerate(cells):
+            if not isinstance(cl, FleetCell):
+                raise TypeError(
+                    f"plan_fleet needs repro.fleet.FleetCell cells, got "
+                    f"{type(cl).__name__}")
+            resolved[i] = resolve_fleet_cell(cl)
+    with TraceAnnotation("repro.plan.entries"):
+        entries: dict[int, list] = {}
+        groups: dict[tuple, list[int]] = {}
+        codes, acodes = {}, {}
+        arrivals = 0
+        for i, cl in enumerate(cells):
+            rs = resolved[i]
+            arrivals += len(rs.times)
+            codes[i] = get_dispatch_policy(cl.dispatcher).code
+            acodes[i] = get_admission_policy(cl.admission).code
+            entries[i] = _entries(rs.times, cl.fleet.T_s, rs.horizon_s,
+                                  payload=rs.tids)
+            n_e = len(entries[i])
+            E = (_pad_pow2(n_e, lo=4) if n_e <= 256
+                 else 256 * int(math.ceil(n_e / 256)))
+            N_pad = _pad_pow2(rs.n_tenants, lo=4)
+            groups.setdefault((E, N_pad, fail_static(rs.failures)),
+                              []).append(i)
 
     def _proxy(i: int) -> EventCell:
         # an EventCell twin carrying the cell's fleet/objective axes so
@@ -421,50 +490,52 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
         tbl[4, :n] = rs.adm_quota
         return tbl
 
-    dispatches: list[ChunkDispatch] = []
-    for (E, N_pad, fstat), idxs in groups.items():
-        chunk = _pad_pow2(len(idxs), lo=4, hi=EV_CHUNK_MAX)
-        start = 0
-        while start < len(idxs):
-            sl = idxs[start:start + chunk]
-            start += chunk
-            pad = sl + [sl[0]] * (chunk - len(sl))
-            times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
-            tids = np.zeros((len(pad), E, BLOCK), np.int32)
-            tick_t = np.zeros((len(pad), E), np.float32)
-            is_tick = np.zeros((len(pad), E), bool)
-            for r, i in enumerate(pad):
-                for e, (row, prow, tick) in enumerate(entries[i]):
-                    times[r, e, :len(row)] = row
-                    tids[r, e, :len(prow)] = prow
-                    if tick is not None:
-                        tick_t[r, e] = tick
-                        is_tick[r, e] = True
-            tables = np.stack([_tenant_table(i, N_pad) for i in pad])
-            arrays = {
-                "scalars": np.array([_scalars(_proxy(i))[:-2] for i in pad],
-                                    np.float32),
-                "fail_seed": np.array(
-                    [(resolved[i].failures.seed
-                      if resolved[i].failures is not None else 0)
-                     for i in pad], np.uint32),
-                "max_fpgas": np.array([cells[i].fleet.max_fpgas
-                                       for i in pad], np.int32),
-                "allocate": np.array([cells[i].allocate_fpgas
-                                      for i in pad], bool),
-                "codes": np.array([codes[i] for i in pad], np.int32),
-                "acodes": np.array([acodes[i] for i in pad], np.int32),
-                "times": times, "tids": tids,
-                "tick_t": tick_t, "is_tick": is_tick,
-                "ta_size": tables[:, 0], "ta_deadline": tables[:, 1],
-                "adm_rate": tables[:, 2], "adm_burst": tables[:, 3],
-                "adm_quota": tables[:, 4],
-            }
-            dispatches.append(ChunkDispatch(
-                kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
-                arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
+    with TraceAnnotation("repro.plan.pack"):
+        dispatches: list[ChunkDispatch] = []
+        for (E, N_pad, fstat), idxs in groups.items():
+            chunk = _pad_pow2(len(idxs), lo=4, hi=EV_CHUNK_MAX)
+            start = 0
+            while start < len(idxs):
+                sl = idxs[start:start + chunk]
+                start += chunk
+                pad = sl + [sl[0]] * (chunk - len(sl))
+                times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
+                tids = np.zeros((len(pad), E, BLOCK), np.int32)
+                tick_t = np.zeros((len(pad), E), np.float32)
+                is_tick = np.zeros((len(pad), E), bool)
+                for r, i in enumerate(pad):
+                    for e, (row, prow, tick) in enumerate(entries[i]):
+                        times[r, e, :len(row)] = row
+                        tids[r, e, :len(prow)] = prow
+                        if tick is not None:
+                            tick_t[r, e] = tick
+                            is_tick[r, e] = True
+                tables = np.stack([_tenant_table(i, N_pad) for i in pad])
+                arrays = {
+                    "scalars": np.array(
+                        [_scalars(_proxy(i))[:-2] for i in pad], np.float32),
+                    "fail_seed": np.array(
+                        [(resolved[i].failures.seed
+                          if resolved[i].failures is not None else 0)
+                         for i in pad], np.uint32),
+                    "max_fpgas": np.array([cells[i].fleet.max_fpgas
+                                           for i in pad], np.int32),
+                    "allocate": np.array([cells[i].allocate_fpgas
+                                          for i in pad], bool),
+                    "codes": np.array([codes[i] for i in pad], np.int32),
+                    "acodes": np.array([acodes[i] for i in pad], np.int32),
+                    "times": times, "tids": tids,
+                    "tick_t": tick_t, "is_tick": is_tick,
+                    "ta_size": tables[:, 0], "ta_deadline": tables[:, 1],
+                    "adm_rate": tables[:, 2], "adm_burst": tables[:, 3],
+                    "adm_quota": tables[:, 4],
+                }
+                dispatches.append(ChunkDispatch(
+                    kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
+                    arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
 
-    return SweepPlan("fleet", cells, dispatches, n_max)
+    return SweepPlan("fleet", cells, dispatches, n_max,
+                     meta=_counters(dispatches, entries, arrivals))
 
 
 class SweepResult:
