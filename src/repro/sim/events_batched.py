@@ -806,10 +806,12 @@ class EventCell:
                 f"{np.shape(self.seed)}")
 
 
-# Cost: O(intervals x arrivals) per cell (``idx == k`` over every arrival
-# for each of the K + 1 buckets), and `plan_events` runs it once per cell,
-# so a stream shared by several dispatchers is bucketed once per
-# dispatcher. Its share of planning is the ``repro.plan.entries`` span.
+# Cost: O(arrivals + intervals) per cell: one bucket index per arrival,
+# one `np.searchsorted` for the K + 2 bucket bounds, then slices of the
+# original array. The stream must be sorted ascending (`EventCell`
+# refuses an unsorted one, `resolve_fleet_cell` stable-sorts its merged
+# stream), so each bucket is one contiguous run. `plan_events` runs it
+# once per cell; its share of planning is the ``repro.plan.entries`` span.
 def _entries(arr: np.ndarray, interval_s: float, horizon: float,
              payload: np.ndarray | None = None) -> list[tuple]:
     """Flat entry stream for one cell: fixed-width arrival blocks with
@@ -821,29 +823,28 @@ def _entries(arr: np.ndarray, interval_s: float, horizon: float,
     With ``payload`` (a per-arrival array aligned with ``arr``, e.g. the
     fleet layer's tenant indices) entries are ``(row, pay_row, tick)``
     3-tuples, the payload sliced identically to the times; otherwise the
-    original ``(row, tick)`` 2-tuples."""
+    original ``(row, tick)`` 2-tuples. Raises ValueError if ``arr`` is
+    not sorted ascending."""
+    arr = np.asarray(arr)
     K = int(np.ceil(horizon / interval_s))
     idx = np.minimum(np.ceil(np.asarray(arr, np.float64) / interval_s)
                      .astype(np.int64), K)
     idx = np.maximum(idx, 0)
+    if np.any(idx[1:] < idx[:-1]):
+        raise ValueError("_entries needs an arrival stream sorted ascending")
+    bounds = np.searchsorted(idx, np.arange(K + 2)).tolist()
+    pay = None if payload is None else np.asarray(payload)
     out: list[tuple] = []
     for k in range(K + 1):
-        sel = idx == k
-        b = np.asarray(arr)[sel]
-        blocks = ([b[j:j + BLOCK] for j in range(0, len(b), BLOCK)]
-                  or [b[:0]])
-        if payload is not None:
-            p = np.asarray(payload)[sel]
-            pblocks = ([p[j:j + BLOCK] for j in range(0, len(p), BLOCK)]
-                       or [p[:0]])
+        lo, hi = bounds[k], bounds[k + 1]
         tick = k * interval_s if k < K else None
-        if payload is None:
-            out.extend((r, None) for r in blocks[:-1])
-            out.append((blocks[-1], tick))
-        else:
-            out.extend((r, pr, None)
-                       for r, pr in zip(blocks[:-1], pblocks[:-1]))
-            out.append((blocks[-1], pblocks[-1], tick))
+        starts = range(lo, hi, BLOCK) or range(lo, lo + 1)
+        last = starts[-1]
+        for j in starts:
+            t = tick if j == last else None
+            e = min(j + BLOCK, hi)
+            out.append((arr[j:e], t) if pay is None
+                       else (arr[j:e], pay[j:e], t))
     return out
 
 
