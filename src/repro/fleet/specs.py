@@ -17,7 +17,15 @@ fingerprint:
     tenant's arrivals, merge them into one time-ordered tenant-tagged
     stream (stable sort: equal-time arrivals keep tenant-index order, so
     both engines consume the identical stream), and precompute the
-    per-tenant size/deadline/weight and admission-knob tables.
+    per-tenant size/deadline/weight and admission-knob tables. The merge
+    belongs to the tenant population, not the cell: cells that share a
+    population (and differ in dispatcher, admission or fleet) share one
+    merged stream.
+
+Explicit streams are held as read-only float64 arrays; each spec digests
+its stream once and each population (a cell's tenants and seed) hashes
+once, at construction: hashing or comparing a cell of thousands of
+tenants never walks their arrivals again.
 
 Trust order matches the single-tenant engines (docs/architecture.md
 "Fleet layer"): `repro.fleet.oracle.FleetSim` is the exact serial
@@ -27,10 +35,13 @@ oracle, `repro.fleet.engine` the batched twin.
 from __future__ import annotations
 
 import functools
+import hashlib
 from dataclasses import dataclass
 from typing import Any, NamedTuple
 
 import numpy as np
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.workers import DEFAULT_FLEET, FleetParams
 from repro.ft.failures import FailureSpec
@@ -42,20 +53,51 @@ from repro.policies import get_admission_policy, get_dispatch_policy
 SLO_CLASSES = {"tight": 5.0, "standard": 10.0, "relaxed": 20.0}
 
 
-@dataclass(frozen=True)
+def _immutable(a: np.ndarray) -> bool:
+    """Whether nothing can write to ``a``'s memory: it and every array
+    it views are read-only, down to an owner or an immutable buffer."""
+    while isinstance(a, np.ndarray):
+        if a.flags.writeable:
+            return False
+        if a.base is None:
+            return True
+        a = a.base
+    return isinstance(a, bytes)
+
+
+def _stream(times) -> np.ndarray:
+    """An explicit arrival stream as a read-only float64 array: a
+    contiguous float64 array that nothing can write to passes through,
+    anything else is copied."""
+    if (isinstance(times, np.ndarray) and times.dtype == np.float64
+            and times.ndim == 1 and times.flags.c_contiguous
+            and _immutable(times)):
+        return times
+    a = np.array(times, np.float64).reshape(-1)
+    a.setflags(write=False)
+    return a
+
+
+@dataclass(frozen=True, eq=False)
 class TenantSpec:
     """One tenant of a shared fleet (frozen + hashable: plan group key,
     checkpoint fingerprint input).
 
     Demand is exactly one of: a named workload ``scenario``
     (`repro.workloads.scenarios.ScenarioSpec`, realized at ``seed``) or
-    an explicit ``arrival_times`` tuple (+ ``request_size_s``). ``slo``
-    names a `SLO_CLASSES` deadline multiplier; ``weight`` is the
+    an explicit ``arrival_times`` stream (+ ``request_size_s``), given as
+    any sequence of seconds and kept as a read-only float64 array.
+    ``slo`` names a `SLO_CLASSES` deadline multiplier; ``weight`` is the
     fairness share the admission policy consumes
-    (`repro.policies.admission.AdmissionPolicy.tenant_params`)."""
+    (`repro.policies.admission.AdmissionPolicy.tenant_params`).
+
+    Identity is fixed at construction: the stream's bytes are digested
+    once, so hashing and comparing specs costs the same whatever their
+    length (two specs are equal when every field and the stream's bytes
+    are)."""
 
     scenario: Any = None                   # ScenarioSpec | None
-    arrival_times: tuple | None = None     # explicit stream (seconds)
+    arrival_times: np.ndarray | None = None   # explicit stream (seconds)
     request_size_s: float | None = None    # None -> scenario's size
     slo: str = "standard"
     weight: float = 1.0
@@ -67,12 +109,11 @@ class TenantSpec:
             raise ValueError(
                 "TenantSpec needs exactly one of scenario= or "
                 "arrival_times=")
+        digest = None
         if self.arrival_times is not None:
-            if not isinstance(self.arrival_times, tuple):
-                object.__setattr__(self, "arrival_times",
-                                   tuple(float(t)
-                                         for t in self.arrival_times))
-            a = np.asarray(self.arrival_times, np.float64)
+            a = _stream(self.arrival_times)
+            object.__setattr__(self, "arrival_times", a)
+            digest = hashlib.blake2b(a.data, digest_size=16).digest()
             if a.size and (not np.all(np.isfinite(a)) or np.any(a < 0)
                            or np.any(np.diff(a) < 0)):
                 raise ValueError(
@@ -95,10 +136,45 @@ class TenantSpec:
         if not (np.isfinite(self.weight) and self.weight > 0):
             raise ValueError(
                 f"TenantSpec.weight must be > 0, got {self.weight!r}")
+        key = (self.scenario, digest, self.request_size_s, self.slo,
+               self.weight, self.seed, self.failures)
+        object.__setattr__(self, "_key", key)
+
+    def __eq__(self, other) -> bool:
+        return self is other or (type(other) is TenantSpec
+                                 and self._key == other._key)
+
+    def __hash__(self) -> int:
+        return hash(self._key)
 
     @property
     def deadline_mult(self) -> float:
         return SLO_CLASSES[self.slo]
+
+
+class Population:
+    """A tenant population at one seed offset: what a merged stream
+    depends on. Hashes once; cells built from one ``tenants`` tuple
+    compare equal by identity, without walking it."""
+
+    __slots__ = ("tenants", "seed", "_hash")
+
+    def __init__(self, tenants: tuple, seed: int):
+        self.tenants, self.seed = tenants, seed
+        self._hash = hash((tenants, seed))
+
+    def __eq__(self, other) -> bool:
+        return self is other or (
+            type(other) is Population and self._hash == other._hash
+            and self.seed == other.seed and self.tenants == other.tenants)
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def __reduce__(self):
+        # rebuilt through __init__: string hashes differ between
+        # processes, so the hash is computed where it is loaded
+        return Population, (self.tenants, self.seed)
 
 
 @dataclass(frozen=True)
@@ -143,6 +219,13 @@ class FleetCell:
             raise ValueError(
                 f"FleetCell.energy_weight must be finite, got "
                 f"{self.energy_weight!r}")
+        object.__setattr__(self, "population",
+                           Population(self.tenants, self.seed))
+
+    def __hash__(self) -> int:
+        return hash((self.population, self.dispatcher, self.admission,
+                     self.fleet, self.energy_weight, self.horizon_s,
+                     self.allocate_fpgas, self.failures, self.tag))
 
     @property
     def n_tenants(self) -> int:
@@ -169,11 +252,28 @@ class ResolvedFleet(NamedTuple):
         return len(self.sizes)
 
 
-@functools.lru_cache(maxsize=64)
-def resolve_fleet_cell(cell: FleetCell) -> ResolvedFleet:
-    """Materialize one `FleetCell` (cached — cells are frozen/hashable,
-    and the planner, the execution scatter and the oracle all re-resolve
-    the same cells).
+class _Merged(NamedTuple):
+    """A population's merged stream and per-tenant tables (read-only)."""
+
+    times: np.ndarray
+    tids: np.ndarray
+    sizes: np.ndarray
+    deadlines: np.ndarray
+    weights: np.ndarray
+    horizon_s: float | None          # longest scenario horizon, if any
+    failures: frozenset              # distinct tenant-level FailureSpecs
+
+
+def _frozen(a: np.ndarray) -> np.ndarray:
+    a.setflags(write=False)
+    return a
+
+
+@functools.lru_cache(maxsize=16)
+def merge_population(pop: Population) -> _Merged:
+    """Merge one population's tenant streams (cached per population:
+    every cell that shares it, in a plan or across plans, reuses one
+    merge). Runs inside the ``repro.fleet.resolve`` span.
 
     Scenario-bearing tenants are synthesized in ONE batched dispatch per
     distinct `ScenarioSpec` (`repro.workloads.scenarios.scenario_traces`
@@ -185,64 +285,82 @@ def resolve_fleet_cell(cell: FleetCell) -> ResolvedFleet:
     tenant order and stable-sorting by time, so equal-time arrivals keep
     tenant-index order — the documented cross-engine tie rule (both
     engines consume these exact arrays)."""
-    n = len(cell.tenants)
-    streams: list = [None] * n
-    sizes: list = [None] * n
-    pending: dict = {}
-    for i, spec in enumerate(cell.tenants):
-        if spec.arrival_times is not None:
-            streams[i] = np.asarray(spec.arrival_times, np.float64)
-            sizes[i] = float(spec.request_size_s)
-        else:
-            pending.setdefault(spec.scenario, []).append(i)
-    if pending:
-        from repro.workloads.scenarios import (scenario_arrivals,
-                                               scenario_traces)
-        for sc, idxs in pending.items():
-            seeds = sorted({cell.seed + cell.tenants[i].seed for i in idxs})
-            by_seed = dict(zip(seeds, scenario_traces(sc, seeds)))
-            for i in idxs:
-                spec = cell.tenants[i]
-                s = cell.seed + spec.seed
-                streams[i] = np.asarray(
-                    scenario_arrivals(sc, s, _trace=by_seed[s]), np.float64)
-                sizes[i] = float(spec.request_size_s
-                                 if spec.request_size_s is not None
-                                 else by_seed[s].request_size_s)
-    n_per = [len(a) for a in streams]
-    times = (np.concatenate(streams) if streams
-             else np.zeros(0, np.float64))
-    tids = np.repeat(np.arange(len(streams), dtype=np.int32), n_per)
-    order = np.argsort(times, kind="stable")
-    times, tids = times[order], tids[order]
+    tenants, seed = pop.tenants, pop.seed
+    n = len(tenants)
+    with TraceAnnotation("repro.fleet.resolve", tenants=n):
+        streams: list = [None] * n
+        sizes: list = [None] * n
+        pending: dict = {}
+        for i, spec in enumerate(tenants):
+            if spec.arrival_times is not None:
+                streams[i] = spec.arrival_times
+                sizes[i] = float(spec.request_size_s)
+            else:
+                pending.setdefault(spec.scenario, []).append(i)
+        if pending:
+            from repro.workloads.scenarios import (scenario_arrivals,
+                                                   scenario_traces)
+            for sc, idxs in pending.items():
+                seeds = sorted({seed + tenants[i].seed for i in idxs})
+                by_seed = dict(zip(seeds, scenario_traces(sc, seeds)))
+                for i in idxs:
+                    spec = tenants[i]
+                    s = seed + spec.seed
+                    streams[i] = np.asarray(
+                        scenario_arrivals(sc, s, _trace=by_seed[s]),
+                        np.float64)
+                    sizes[i] = float(spec.request_size_s
+                                     if spec.request_size_s is not None
+                                     else by_seed[s].request_size_s)
+        n_per = [len(a) for a in streams]
+        times = (np.concatenate(streams) if streams
+                 else np.zeros(0, np.float64))
+        tids = np.repeat(np.arange(len(streams), dtype=np.int32), n_per)
+        order = np.argsort(times, kind="stable")
+        times, tids = times[order], tids[order]
 
-    sizes = np.asarray(sizes, np.float64)
-    deadlines = sizes * np.array([t.deadline_mult for t in cell.tenants],
-                                 np.float64)
-    weights = np.array([t.weight for t in cell.tenants], np.float64)
+        sizes = np.asarray(sizes, np.float64)
+        deadlines = sizes * np.array([t.deadline_mult for t in tenants],
+                                     np.float64)
+        weights = np.array([t.weight for t in tenants], np.float64)
+        sc_h = [float(t.scenario.horizon_s) for t in tenants
+                if t.scenario is not None]
+        return _Merged(times=_frozen(times), tids=_frozen(tids),
+                       sizes=_frozen(sizes), deadlines=_frozen(deadlines),
+                       weights=_frozen(weights),
+                       horizon_s=max(sc_h) if sc_h else None,
+                       failures=frozenset(t.failures for t in tenants
+                                          if t.failures is not None))
+
+
+@functools.lru_cache(maxsize=64)
+def resolve_fleet_cell(cell: FleetCell) -> ResolvedFleet:
+    """Materialize one `FleetCell` (cached — cells are frozen/hashable,
+    and the planner, the execution scatter and the oracle all re-resolve
+    the same cells): the population's merged stream and tables
+    (`merge_population`, shared by every cell of that population) plus
+    the cell's own admission knobs, horizon and fault model."""
+    m = merge_population(cell.population)
     rate, burst, quota = get_admission_policy(
-        cell.admission).tenant_params(weights)
+        cell.admission).tenant_params(m.weights)
 
     if cell.horizon_s is not None:
         horizon = float(cell.horizon_s)
+    elif m.horizon_s is not None:
+        horizon = m.horizon_s
     else:
-        sc = [float(t.scenario.horizon_s) for t in cell.tenants
-              if t.scenario is not None]
-        horizon = (max(sc) if sc
-                   else float(times[-1] + 1.0) if len(times) else 1.0)
+        horizon = float(m.times[-1] + 1.0) if len(m.times) else 1.0
 
     failures = cell.failures
     if failures is None:
-        tenant_f = {t.failures for t in cell.tenants
-                    if t.failures is not None}
-        if len(tenant_f) > 1:
+        if len(m.failures) > 1:
             raise ValueError(
                 "conflicting per-tenant FailureSpecs on one shared fleet "
                 "(set FleetCell.failures to pick one)")
-        failures = next(iter(tenant_f)) if tenant_f else None
+        failures = next(iter(m.failures)) if m.failures else None
 
-    return ResolvedFleet(times=times, tids=tids, sizes=sizes,
-                         deadlines=deadlines, weights=weights,
+    return ResolvedFleet(times=m.times, tids=m.tids, sizes=m.sizes,
+                         deadlines=m.deadlines, weights=m.weights,
                          adm_rate=np.asarray(rate, np.float32),
                          adm_burst=np.asarray(burst, np.float32),
                          adm_quota=np.asarray(quota, np.float32),

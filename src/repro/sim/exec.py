@@ -335,7 +335,9 @@ def _execute_fleet(plan: SweepPlan, backend: Backend,
     the fleet-level requests / work / misses / work-split are computed
     from the per-tenant accumulators themselves (then energy/cost are
     attributed back out of the fleet totals), so the tenant rows always
-    reconcile — `repro.sim.harness.check_fleet_result` enforces it."""
+    reconcile — `repro.sim.harness.check_fleet_result` enforces it.
+    The result's ``meta`` counts the plan's shed requests
+    (``shed_requests``)."""
     from repro.core.metrics import attribute_tenants
     from repro.fleet.specs import resolve_fleet_cell
 
@@ -389,4 +391,6 @@ def _execute_fleet(plan: SweepPlan, backend: Backend,
                             n_dispatches=plan.n_dispatches,
                             backend=backend.name,
                             n_devices=backend.n_devices,
-                            dispatch_devices=devs)
+                            dispatch_devices=devs,
+                            meta={"shed_requests": sum(
+                                t.breakdown["shed_requests"] for t in out)})

@@ -180,9 +180,12 @@ class SweepPlan:
     ``meta`` holds the plan's ``plan_id`` and its size counters, sums
     over dispatches or cells that `repro.sim.exec.execute` copies into
     the result's ``meta``: ``cells`` (real rows), ``rows`` (chunk),
-    ``h2d_bytes`` (the dispatch arrays), and for event and fleet plans
+    ``h2d_bytes`` (the dispatch arrays), for event and fleet plans
     ``row_entries`` (real rows x E), ``entries_scanned`` (chunk x E),
-    ``entries`` (real entries) and ``arrivals`` (real arrivals)."""
+    ``entries`` (real entries) and ``arrivals`` (real arrivals), and for
+    fleet plans ``tenants`` (real tenants), ``tenant_slots`` (padded
+    tenant tables, N_pad) and ``populations`` (distinct tenant
+    populations, each merged once)."""
 
     kind: str                       # "rate" | "event"
     cells: list
@@ -427,7 +430,9 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     tenant count. Groups key on (padded entry count, padded tenant
     count, failure static), so a 1024-tenant policy x seed grid whose
     cells share stream/tenant shape is a handful of dispatches
-    (benchmarks/fleet_suite.py asserts the budget).
+    (benchmarks/fleet_suite.py asserts the budget). Cells that share a
+    tenant population share its merged stream
+    (`repro.fleet.specs.merge_population`) and its entry stream.
 
     Execution: `repro.sim.exec` routes ``kind="fleet"`` dispatches to
     `repro.fleet.engine` on either backend; `repro.sim.sweep.sweep_fleet`
@@ -446,20 +451,25 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
             resolved[i] = resolve_fleet_cell(cl)
     with TraceAnnotation("repro.plan.entries"):
         entries: dict[int, list] = {}
+        by_stream: dict[tuple, list] = {}    # cells of one population
         groups: dict[tuple, list[int]] = {}
         codes, acodes = {}, {}
-        arrivals = 0
+        arrivals = tenant_slots = 0
         for i, cl in enumerate(cells):
             rs = resolved[i]
             arrivals += len(rs.times)
             codes[i] = get_dispatch_policy(cl.dispatcher).code
             acodes[i] = get_admission_policy(cl.admission).code
-            entries[i] = _entries(rs.times, cl.fleet.T_s, rs.horizon_s,
-                                  payload=rs.tids)
+            key = (cl.population, cl.fleet.T_s, rs.horizon_s)
+            if key not in by_stream:
+                by_stream[key] = _entries(rs.times, cl.fleet.T_s,
+                                          rs.horizon_s, payload=rs.tids)
+            entries[i] = by_stream[key]
             n_e = len(entries[i])
             E = (_pad_pow2(n_e, lo=4) if n_e <= 256
                  else 256 * int(math.ceil(n_e / 256)))
             N_pad = _pad_pow2(rs.n_tenants, lo=4)
+            tenant_slots += N_pad
             groups.setdefault((E, N_pad, fail_static(rs.failures)),
                               []).append(i)
 
@@ -534,8 +544,11 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                     kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
                     arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
 
-    return SweepPlan("fleet", cells, dispatches, n_max,
-                     meta=_counters(dispatches, entries, arrivals))
+    meta = _counters(dispatches, entries, arrivals)
+    meta.update(tenants=sum(rs.n_tenants for rs in resolved.values()),
+                tenant_slots=tenant_slots,
+                populations=len({cl.population for cl in cells}))
+    return SweepPlan("fleet", cells, dispatches, n_max, meta=meta)
 
 
 class SweepResult:

@@ -322,6 +322,120 @@ def test_resolved_stream_is_stable_merge():
     np.testing.assert_array_equal(rs.tids, [0, 0, 0, 1, 1])
 
 
+@pytest.mark.parametrize("admission", ["admit_all", "token_bucket"])
+def test_array_and_tuple_streams_give_identical_results(admission):
+    """A stream given as a float64 array and as a tuple of floats builds
+    equal specs, and cells built either way give bit-identical totals
+    and tenant rows, each resolved from scratch."""
+    from repro.fleet.specs import merge_population
+    rng = np.random.default_rng(13)
+    raw = [np.sort(rng.integers(0, 480, n)) / 8.0 for n in (50, 90, 140)]
+
+    def cell(streams):
+        return FleetCell(tenants=tuple(
+            TenantSpec(arrival_times=a, request_size_s=0.125,
+                       weight=(0.5, 1.0, 2.0)[i])
+            for i, a in enumerate(streams)),
+            admission=admission, fleet=QFLEET, horizon_s=60.0)
+
+    by_array = cell([a.copy() for a in raw])
+    by_tuple = cell([tuple(float(t) for t in a) for a in raw])
+    assert by_array == by_tuple and hash(by_array) == hash(by_tuple)
+    out = []
+    for c in (by_array, by_tuple):
+        resolve_fleet_cell.cache_clear()
+        merge_population.cache_clear()
+        res = sweep_fleet([c], n_max=64, w_fpga=16, w_cpu=32)
+        out.append((res.totals(0), [r.row() for r in res.tenants(0)]))
+    (ta, rows_a), (tb, rows_b) = out
+    for f in EXACT_FIELDS + CLOSE_FIELDS:
+        assert getattr(ta, f) == getattr(tb, f), f
+    assert ta.breakdown == tb.breakdown
+    assert rows_a == rows_b
+
+
+def test_population_shared_by_cells_merges_once():
+    """Cells that share a tenant population (differing in dispatcher and
+    admission) share one merged stream, merged once per plan; a spec's
+    stream is a read-only array and a cell hashes without walking it."""
+    from repro.fleet.specs import merge_population
+    tenants = dyadic_tenants(seed=21, n=3)
+    cells = [FleetCell(tenants=tenants, dispatcher=d, admission=a,
+                       fleet=QFLEET, horizon_s=60.0)
+             for d in ("spork", "round_robin")
+             for a in ("admit_all", "token_bucket")]
+    before = merge_population.cache_info().misses
+    plan = plan_fleet(cells, n_max=64, w_fpga=16, w_cpu=32)
+    assert merge_population.cache_info().misses == before + 1
+    assert plan.meta["populations"] == 1
+    rs = [resolve_fleet_cell(c) for c in cells]
+    assert all(r.times is rs[0].times and r.tids is rs[0].tids for r in rs)
+    assert rs[0].adm_burst is not rs[1].adm_burst    # per-cell knobs
+    with pytest.raises(ValueError):
+        tenants[0].arrival_times[0] = 1.0
+    with pytest.raises(ValueError):
+        rs[0].times[0] = 1.0
+    assert cells[0].population == cells[1].population
+    assert hash(cells[0].population) == cells[0].population._hash
+
+
+def test_stream_is_copied_unless_nothing_can_write_to_it():
+    """A read-only view of a writable buffer is copied, so writing to the
+    buffer later moves neither the spec's stream nor its identity; an
+    array that nothing can write to is kept as it is."""
+    buf = np.array([0.5, 1.0, 2.0, 4.0])
+    view = buf[:3]
+    view.setflags(write=False)
+    spec = TenantSpec(arrival_times=view, request_size_s=0.1)
+    twin = TenantSpec(arrival_times=(0.5, 1.0, 2.0), request_size_s=0.1)
+    assert spec.arrival_times is not view and spec == twin
+    buf[0] = 3.0
+    np.testing.assert_array_equal(spec.arrival_times, [0.5, 1.0, 2.0])
+    assert spec == twin and hash(spec) == hash(twin)
+    owned = np.array([0.5, 1.0, 2.0])
+    owned.setflags(write=False)
+    part = owned[1:]
+    assert TenantSpec(arrival_times=owned,
+                      request_size_s=0.1).arrival_times is owned
+    assert TenantSpec(arrival_times=part,
+                      request_size_s=0.1).arrival_times is part
+
+
+def test_cells_fingerprint_again_where_they_are_loaded():
+    """A pickled cell is rebuilt through its constructor, so its cached
+    hash is the loading process's own: another process (string hashes
+    seeded differently) finds it equal to, and in a dict with, a cell it
+    built itself."""
+    import pickle
+    tenants = dyadic_tenants(seed=2, n=2)
+    cell = FleetCell(tenants=tenants, admission=TokenBucket(rate=0.5),
+                     fleet=QFLEET, horizon_s=60.0)
+    assert pickle.loads(pickle.dumps(cell)) == cell
+    script = textwrap.dedent("""
+    import pickle, sys
+    sys.path.insert(0, "src")
+    import test_fleet as t
+    from repro.fleet import FleetCell
+    from repro.policies.admission import TokenBucket
+    got = pickle.loads(sys.stdin.buffer.read())
+    mine = FleetCell(tenants=t.dyadic_tenants(seed=2, n=2),
+                     admission=TokenBucket(rate=0.5), fleet=t.QFLEET,
+                     horizon_s=60.0)
+    assert got == mine and {mine: 1}[got] == 1
+    assert {t_: 1 for t_ in mine.tenants}[got.tenants[1]] == 1
+    print("REBUILT_OK")
+    """)
+    root = os.path.dirname(os.path.dirname(__file__))
+    env = dict(os.environ, PYTHONHASHSEED="12345", JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([os.path.join(root, "tests"),
+                                           os.path.join(root, "src")]))
+    out = subprocess.run([sys.executable, "-c", script],
+                         input=pickle.dumps(cell), capture_output=True,
+                         cwd=root, env=env)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert b"REBUILT_OK" in out.stdout
+
+
 def test_tenant_population_shape():
     pop = tenant_population(16, zipf_a=1.0, seed=3)
     assert len(pop) == 16

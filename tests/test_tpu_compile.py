@@ -8,8 +8,10 @@ compile, for one v5e chip of a described ``v5e:2x2`` host:
     the widths the simulator grids use (a kernel Mosaic cannot lower
     fails here, not on the chip);
   * one 32-cell chunk of the DES engine (W = 32 + 64 worker slots,
-    ``n_max=128``), one fleet-engine chunk and one rate-engine chunk —
-    the programs `repro.sim.exec.LocalBackend` dispatches;
+    ``n_max=128``), one fleet-engine chunk, the benchmark's FaaS pool
+    fleet chunk (W = 192 + 320, ``n_max=256``, 8,192 tenants, 8 lanes
+    at E 5,120) and one rate-engine chunk — the programs `repro.sim.exec.LocalBackend`
+    dispatches;
   * the `MeshBackend` program of a DES chunk over all four chips.
 
 The topology is described inside a module-scoped fixture (never at
@@ -148,6 +150,51 @@ def test_fleet_chunk_compiles_for_v5e(one_chip):
     plan = plan_fleet([FleetCell(tenants=pop, admission=a)
                        for a in admission_policy_names()])
     d = plan.dispatches[0]
+    args = _specs_like(_fleet_args(d), one_chip)
+    compiled = fleet_engine._simulate_fleet_cells.lower(*d.static, *args) \
+        .compile()
+    assert compiled.memory_analysis() is not None
+
+
+def _pool_population(blocks: int, n_tenants: int = 8192,
+                     intervals: int = 120, T_s: float = 10.0) -> tuple:
+    """A tenant population whose merged stream fills ``blocks`` arrival
+    blocks of 128 in every interval: evenly spaced arrivals, dealt to
+    the tenants in turn."""
+    from repro.fleet import TenantSpec
+    per = 128 * blocks
+    k = np.repeat(np.arange(intervals), per)
+    j = np.tile(np.arange(per), intervals)
+    times = T_s * (k + (j + 1) / per)
+    return tuple(TenantSpec(arrival_times=times[i::n_tenants],
+                            request_size_s=0.05, weight=1.0 + i % 7)
+                 for i in range(n_tenants))
+
+
+def test_fleet_pool_chunk_compiles_for_v5e(one_chip):
+    """The benchmark's FaaS pool cell as it is planned: two populations
+    of 8,192 tenants over 1200 s, each population's six cells (three
+    dispatchers x two admissions) one 8-lane dispatch, at the cell's
+    entry counts (E 4,864 and 5,120), on a W = 192 + 320 worker table
+    with ``n_max=256``; the longer dispatch is compiled."""
+    from repro.core.workers import DEFAULT_FLEET
+    from repro.fleet import FleetCell
+    from repro.fleet import engine as fleet_engine
+    from repro.policies.admission import TokenBucket
+    from repro.sim.events import DISPATCHERS
+    from repro.sim.exec import _fleet_args
+    from repro.sim.plan import plan_fleet
+    assert DEFAULT_FLEET.T_s == 10.0
+    cells = [FleetCell(tenants=_pool_population(blocks), dispatcher=d,
+                       admission=a, horizon_s=1200.0)
+             for blocks in (39, 41) for d in DISPATCHERS
+             for a in ("admit_all", TokenBucket(rate=4.0, burst=30.0))]
+    plan = plan_fleet(cells, n_max=256, w_fpga=192, w_cpu=320)
+    shapes = sorted((d.chunk, d.n_real, *d.arrays["times"].shape[1:],
+                     d.arrays["ta_size"].shape[1]) for d in plan.dispatches)
+    assert shapes == [(8, 6, 4864, 128, 8192), (8, 6, 5120, 128, 8192)]
+    d = max(plan.dispatches, key=lambda d: d.arrays["times"].shape[1])
+    assert d.static[:3] == (256, 192, 320)
     args = _specs_like(_fleet_args(d), one_chip)
     compiled = fleet_engine._simulate_fleet_cells.lower(*d.static, *args) \
         .compile()

@@ -208,6 +208,12 @@ def direct(plan) -> dict:
            "h2d_bytes": sum(a.nbytes for d in ds for a in d.arrays.values())}
     if plan.kind == "rate":
         return out
+    if plan.kind == "fleet":
+        out.update(
+            tenants=sum(len(c.tenants) for c in plan.cells),
+            tenant_slots=sum(d.n_real * d.arrays["ta_size"].shape[1]
+                             for d in ds),
+            populations=len({(c.tenants, c.seed) for c in plan.cells}))
     real = [(d.arrays["times"][:d.n_real], d.arrays["is_tick"][:d.n_real])
             for d in ds]
     out.update(
@@ -225,6 +231,7 @@ PLANS = {"event": lambda: plan_events(event_cells(), **SMALL),
          "rate": lambda: plan_sweep(rate_cells())}
 ENTRY_COUNTERS = ("cells", "rows", "row_entries", "entries_scanned",
                   "entries", "arrivals", "h2d_bytes")
+TENANT_COUNTERS = ("tenants", "tenant_slots", "populations")
 
 
 @pytest.fixture(scope="module")
@@ -235,11 +242,43 @@ def plans():
 @pytest.mark.parametrize("kind,counter", [
     *(("event", c) for c in ENTRY_COUNTERS),
     *(("fleet", c) for c in ENTRY_COUNTERS),
+    *(("fleet", c) for c in TENANT_COUNTERS),
     *(("rate", c) for c in ("cells", "rows", "h2d_bytes"))])
 def test_counter_equals_a_direct_count(plans, kind, counter):
     plan = plans[kind]
     assert plan.meta[counter] == direct(plan)[counter]
     assert type(plan.meta[counter]) is int
+
+
+def test_event_plans_count_no_tenants(plans):
+    assert not set(TENANT_COUNTERS) & set(plans["event"].meta)
+
+
+def test_population_merge_nests_in_plan_resolve(tmp_path):
+    """Each distinct population is merged once, inside the planner's
+    resolve phase; the shed counter is the cells' shed requests."""
+    from dataclasses import replace
+
+    from repro.fleet.specs import merge_population, resolve_fleet_cell
+    from repro.policies.admission import TokenBucket
+    from repro.sim.sweep import sweep_fleet
+    starved = TokenBucket(rate=0.5, burst=2.0)
+    cells = [replace(c, admission=starved)
+             if c.admission == "token_bucket" else c for c in fleet_cells()]
+    resolve_fleet_cell.cache_clear()
+    merge_population.cache_clear()
+    res, spans = traced(tmp_path, lambda: sweep_fleet(cells, **SMALL))
+    (resolve,) = named(spans, "repro.plan.resolve")
+    merges = named(spans, "repro.fleet.resolve")
+    assert len(merges) == res.meta["populations"] == 2
+    assert all(inside(m, resolve) for m in merges)
+    assert sorted(m.stats["tenants"] for m in merges) == [1, 2]
+    shed = [res.totals(i).breakdown["shed_requests"]
+            for i in range(len(cells))]
+    assert res.meta["shed_requests"] == sum(shed) > 0
+    assert sum(shed) == sum(r.shed for i in range(len(cells))
+                            for r in res.tenants(i))
+    assert res.meta["tenants"] == sum(len(c.tenants) for c in cells)
 
 
 def test_rate_plans_count_no_entries(plans):
