@@ -45,7 +45,7 @@ runs in a handful of dispatches:
     worst-case interval's arrival count.
   * `simulate_events_batch` vmaps the whole thing over a cell axis
     (dispatcher x app x seed x objective): one compiled program per
-    (entry-count bucket, n_max, table shape).
+    (padded entry count, cell count, n_max, table shape).
 
 Equivalence contract (tests/test_events_batched.py): on integer-quantized
 instances (arrival times, sizes, spin-ups and timeouts on a coarse dyadic
@@ -856,8 +856,8 @@ def _pad_pow2(n: int, lo: int = 4, hi: int | None = None) -> int:
 def simulate_events_batch(cells: Iterable[EventCell], n_max: int = 512,
                           w_fpga: int = 32, w_cpu: int = 64,
                           backend=None) -> list[RunTotals]:
-    """Run every DES cell, one dispatch per (entry-count bucket) group
-    chunk; cell order is preserved. Totals carry
+    """Run every DES cell, one dispatch per plan chunk (cells packed by
+    entry-stream length); cell order is preserved. Totals carry
     ``breakdown['slot_overflow']`` (0 unless a table region or
     ``max_fpgas`` was too small for the trace).
 

@@ -182,7 +182,9 @@ class SweepPlan:
     the result's ``meta``: ``cells`` (real rows), ``rows`` (chunk),
     ``h2d_bytes`` (the dispatch arrays), for event and fleet plans
     ``row_entries`` (real rows x E), ``entries_scanned`` (chunk x E),
-    ``entries`` (real entries) and ``arrivals`` (real arrivals), and for
+    ``entries`` (real entries), ``arrivals`` (real arrivals),
+    ``serial_entries`` (E) and ``cells_widened`` (real rows whose own
+    padded entry count is below E), and for
     fleet plans ``tenants`` (real tenants), ``tenant_slots`` (padded
     tenant tables, N_pad) and ``populations`` (distinct tenant
     populations, each merged once)."""
@@ -207,7 +209,9 @@ def _counters(dispatches: list[ChunkDispatch], entries: dict | None = None,
     arrival slots the dispatches fill splits into three factors:
     ``row_entries / entries_scanned`` (chunk rounding), ``entries /
     row_entries`` (E rounding), ``arrivals / (BLOCK * entries)``
-    (part-full blocks)."""
+    (part-full blocks); ``serial_entries`` is the scan's length over
+    the dispatches, run one after another, and ``cells_widened``
+    counts the cells scanned past their own padded length."""
     out = {"cells": sum(d.n_real for d in dispatches),
            "rows": sum(d.chunk for d in dispatches),
            "h2d_bytes": sum(a.nbytes for d in dispatches
@@ -217,7 +221,45 @@ def _counters(dispatches: list[ChunkDispatch], entries: dict | None = None,
         out.update(row_entries=sum(d.n_real * E for d, E in widths),
                    entries_scanned=sum(d.chunk * E for d, E in widths),
                    entries=sum(len(e) for e in entries.values()),
-                   arrivals=arrivals)
+                   arrivals=arrivals,
+                   serial_entries=sum(E for _, E in widths),
+                   cells_widened=sum(
+                       _round_entries(len(entries[i])) < E
+                       for d, E in widths for i in d.cell_idx))
+    return out
+
+
+def _round_entries(n_e: int) -> int:
+    """A stream's padded entry count: a power of two up to 256 entries,
+    then a multiple of 256 (the compiled scan lengths)."""
+    return (_pad_pow2(n_e, lo=4) if n_e <= 256
+            else 256 * int(math.ceil(n_e / 256)))
+
+
+def _pack_by_length(groups: dict, entries: dict[int, list]) -> list[tuple]:
+    """Cut each static key's cells (``groups``: key -> cell indices)
+    into dispatches, as ``(key, E, cells, rows)`` in dispatch order:
+    ``cells`` at most `EV_CHUNK_MAX` long, ``rows`` the same padded to a
+    power of two in [4, `EV_CHUNK_MAX`] by repeating the first cell;
+    ``entries`` maps each cell to its entry stream.
+
+    The cells are sorted by padded entry count, longest first (stable:
+    ties keep grid order), and cut into runs of `EV_CHUNK_MAX`; a
+    dispatch scans its longest cell's padded length E. Dispatches run
+    one after another and their lanes side by side, so a grid costs
+    device time by its serial entries (the sum of E) and less by its
+    lanes (on a TPU v5e a 32-lane DES step costs about 1.5 x a 16-lane
+    one): this cut gives the least sum of E over dispatches of at most
+    `EV_CHUNK_MAX` cells. A shorter cell's extra entries are inert
+    (no arrivals, no tick)."""
+    out = []
+    for key, idxs in groups.items():
+        E = {i: _round_entries(len(entries[i])) for i in idxs}
+        order = sorted(idxs, key=lambda i: -E[i])
+        for start in range(0, len(order), EV_CHUNK_MAX):
+            sl = order[start:start + EV_CHUNK_MAX]
+            chunk = _pad_pow2(len(sl), lo=4, hi=EV_CHUNK_MAX)
+            out.append((key, E[sl[0]], sl, sl + [sl[0]] * (chunk - len(sl))))
     return out
 
 
@@ -335,8 +377,9 @@ def plan_sweep(cells: Iterable, n_max: int | None = None) -> SweepPlan:
 @_traced_plan
 def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                 w_cpu: int = 64, resolve: bool = True) -> SweepPlan:
-    """Plan a DES sweep: cells grouped by padded entry-stream length,
-    one `ChunkDispatch` per group chunk, arrays laid out exactly as
+    """Plan a DES sweep: cells grouped by failure static and packed by
+    padded entry-stream length (`_pack_by_length`), one `ChunkDispatch`
+    per chunk, arrays laid out exactly as
     `events_batched._simulate_cells` consumes them. ``resolve=False``
     requires every cell to carry explicit demand already (the engine's
     fail-fast contract: scenario-bearing cells go through
@@ -369,51 +412,39 @@ def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
             horizon = float(cl.horizon_s if cl.horizon_s is not None
                             else (arr[-1] + 1.0 if len(arr) else 1.0))
             entries[i] = _entries(arr, cl.fleet.T_s, horizon)
-            n_e = len(entries[i])
-            # pow2 up to 256 entries, then multiples of 256: every padded
-            # entry costs a full BLOCK of inert arrival slots, so tight
-            # padding beats shape reuse once streams are long.
-            E = (_pad_pow2(n_e, lo=4) if n_e <= 256
-                 else 256 * int(math.ceil(n_e / 256)))
-            # the failure axis's static part joins the group key: disabled
+            # the failure axis's static part is the group key: disabled
             # cells compile (and stay on) the pristine pre-failure program
-            groups.setdefault((E, fail_static(cl.failures)), []).append(i)
+            groups.setdefault(fail_static(cl.failures), []).append(i)
 
     with TraceAnnotation("repro.plan.pack"):
         dispatches: list[ChunkDispatch] = []
-        for (E, fstat), idxs in groups.items():
-            chunk = _pad_pow2(len(idxs), lo=4, hi=EV_CHUNK_MAX)
-            start = 0
-            while start < len(idxs):
-                sl = idxs[start:start + chunk]
-                start += chunk
-                pad = sl + [sl[0]] * (chunk - len(sl))
-                times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
-                tick_t = np.zeros((len(pad), E), np.float32)
-                is_tick = np.zeros((len(pad), E), bool)
-                for r, i in enumerate(pad):
-                    for e, (row, tick) in enumerate(entries[i]):
-                        times[r, e, :len(row)] = row
-                        if tick is not None:
-                            tick_t[r, e] = tick
-                            is_tick[r, e] = True
-                arrays = {
-                    "scalars": np.array([_scalars(cells[i])[:-2] for i in pad],
-                                        np.float32),
-                    "fail_seed": np.array(
-                        [(cells[i].failures.seed
-                          if cells[i].failures is not None else 0)
-                         for i in pad], np.uint32),
-                    "max_fpgas": np.array([cells[i].fleet.max_fpgas
-                                           for i in pad], np.int32),
-                    "allocate": np.array([cells[i].allocate_fpgas
-                                          for i in pad], bool),
-                    "codes": np.array([codes[i] for i in pad], np.int32),
-                    "times": times, "tick_t": tick_t, "is_tick": is_tick,
-                }
-                dispatches.append(ChunkDispatch(
-                    kind="event", static=(n_max, w_fpga, w_cpu, fstat),
-                    arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
+        for fstat, E, sl, pad in _pack_by_length(groups, entries):
+            times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
+            tick_t = np.zeros((len(pad), E), np.float32)
+            is_tick = np.zeros((len(pad), E), bool)
+            for r, i in enumerate(pad):
+                for e, (row, tick) in enumerate(entries[i]):
+                    times[r, e, :len(row)] = row
+                    if tick is not None:
+                        tick_t[r, e] = tick
+                        is_tick[r, e] = True
+            arrays = {
+                "scalars": np.array([_scalars(cells[i])[:-2] for i in pad],
+                                    np.float32),
+                "fail_seed": np.array(
+                    [(cells[i].failures.seed
+                      if cells[i].failures is not None else 0)
+                     for i in pad], np.uint32),
+                "max_fpgas": np.array([cells[i].fleet.max_fpgas
+                                       for i in pad], np.int32),
+                "allocate": np.array([cells[i].allocate_fpgas
+                                      for i in pad], bool),
+                "codes": np.array([codes[i] for i in pad], np.int32),
+                "times": times, "tick_t": tick_t, "is_tick": is_tick,
+            }
+            dispatches.append(ChunkDispatch(
+                kind="event", static=(n_max, w_fpga, w_cpu, fstat),
+                arrays=arrays, cell_idx=tuple(sl), chunk=len(pad)))
 
     return SweepPlan("event", cells, dispatches, n_max,
                      meta=_counters(dispatches, entries, arrivals))
@@ -427,9 +458,10 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     cell's merged tenant-tagged stream (`repro.fleet.resolve_fleet_cell`)
     becomes ``times`` + ``tids`` entry blocks, and per-tenant
     size/deadline/admission tables ride along padded to a power-of-two
-    tenant count. Groups key on (padded entry count, padded tenant
-    count, failure static), so a 1024-tenant policy x seed grid whose
-    cells share stream/tenant shape is a handful of dispatches
+    tenant count. Groups key on (padded tenant count, failure static)
+    and are packed by padded entry count (`_pack_by_length`, as in
+    `plan_events`), so a 1024-tenant policy x seed grid is a handful
+    of dispatches
     (benchmarks/fleet_suite.py asserts the budget). Cells that share a
     tenant population share its merged stream
     (`repro.fleet.specs.merge_population`) and its entry stream.
@@ -465,12 +497,9 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
                 by_stream[key] = _entries(rs.times, cl.fleet.T_s,
                                           rs.horizon_s, payload=rs.tids)
             entries[i] = by_stream[key]
-            n_e = len(entries[i])
-            E = (_pad_pow2(n_e, lo=4) if n_e <= 256
-                 else 256 * int(math.ceil(n_e / 256)))
             N_pad = _pad_pow2(rs.n_tenants, lo=4)
             tenant_slots += N_pad
-            groups.setdefault((E, N_pad, fail_static(rs.failures)),
+            groups.setdefault((N_pad, fail_static(rs.failures)),
                               []).append(i)
 
     def _proxy(i: int) -> EventCell:
@@ -502,47 +531,41 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
 
     with TraceAnnotation("repro.plan.pack"):
         dispatches: list[ChunkDispatch] = []
-        for (E, N_pad, fstat), idxs in groups.items():
-            chunk = _pad_pow2(len(idxs), lo=4, hi=EV_CHUNK_MAX)
-            start = 0
-            while start < len(idxs):
-                sl = idxs[start:start + chunk]
-                start += chunk
-                pad = sl + [sl[0]] * (chunk - len(sl))
-                times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
-                tids = np.zeros((len(pad), E, BLOCK), np.int32)
-                tick_t = np.zeros((len(pad), E), np.float32)
-                is_tick = np.zeros((len(pad), E), bool)
-                for r, i in enumerate(pad):
-                    for e, (row, prow, tick) in enumerate(entries[i]):
-                        times[r, e, :len(row)] = row
-                        tids[r, e, :len(prow)] = prow
-                        if tick is not None:
-                            tick_t[r, e] = tick
-                            is_tick[r, e] = True
-                tables = np.stack([_tenant_table(i, N_pad) for i in pad])
-                arrays = {
-                    "scalars": np.array(
-                        [_scalars(_proxy(i))[:-2] for i in pad], np.float32),
-                    "fail_seed": np.array(
-                        [(resolved[i].failures.seed
-                          if resolved[i].failures is not None else 0)
-                         for i in pad], np.uint32),
-                    "max_fpgas": np.array([cells[i].fleet.max_fpgas
-                                           for i in pad], np.int32),
-                    "allocate": np.array([cells[i].allocate_fpgas
-                                          for i in pad], bool),
-                    "codes": np.array([codes[i] for i in pad], np.int32),
-                    "acodes": np.array([acodes[i] for i in pad], np.int32),
-                    "times": times, "tids": tids,
-                    "tick_t": tick_t, "is_tick": is_tick,
-                    "ta_size": tables[:, 0], "ta_deadline": tables[:, 1],
-                    "adm_rate": tables[:, 2], "adm_burst": tables[:, 3],
-                    "adm_quota": tables[:, 4],
-                }
-                dispatches.append(ChunkDispatch(
-                    kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
-                    arrays=arrays, cell_idx=tuple(sl), chunk=chunk))
+        for (N_pad, fstat), E, sl, pad in _pack_by_length(groups, entries):
+            times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
+            tids = np.zeros((len(pad), E, BLOCK), np.int32)
+            tick_t = np.zeros((len(pad), E), np.float32)
+            is_tick = np.zeros((len(pad), E), bool)
+            for r, i in enumerate(pad):
+                for e, (row, prow, tick) in enumerate(entries[i]):
+                    times[r, e, :len(row)] = row
+                    tids[r, e, :len(prow)] = prow
+                    if tick is not None:
+                        tick_t[r, e] = tick
+                        is_tick[r, e] = True
+            tables = np.stack([_tenant_table(i, N_pad) for i in pad])
+            arrays = {
+                "scalars": np.array(
+                    [_scalars(_proxy(i))[:-2] for i in pad], np.float32),
+                "fail_seed": np.array(
+                    [(resolved[i].failures.seed
+                      if resolved[i].failures is not None else 0)
+                     for i in pad], np.uint32),
+                "max_fpgas": np.array([cells[i].fleet.max_fpgas
+                                       for i in pad], np.int32),
+                "allocate": np.array([cells[i].allocate_fpgas
+                                      for i in pad], bool),
+                "codes": np.array([codes[i] for i in pad], np.int32),
+                "acodes": np.array([acodes[i] for i in pad], np.int32),
+                "times": times, "tids": tids,
+                "tick_t": tick_t, "is_tick": is_tick,
+                "ta_size": tables[:, 0], "ta_deadline": tables[:, 1],
+                "adm_rate": tables[:, 2], "adm_burst": tables[:, 3],
+                "adm_quota": tables[:, 4],
+            }
+            dispatches.append(ChunkDispatch(
+                kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
+                arrays=arrays, cell_idx=tuple(sl), chunk=len(pad)))
 
     meta = _counters(dispatches, entries, arrivals)
     meta.update(tenants=sum(rs.n_tenants for rs in resolved.values()),

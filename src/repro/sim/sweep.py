@@ -145,7 +145,7 @@ def sweep_events(cells: Iterable[EventCell], n_max: int = 512,
 
     The exact discrete-event counterpart of `sweep`: every `EventCell`
     (dispatcher x arrival trace x fleet x objective) runs on the batched
-    `repro.sim.events_batched` engine, grouped by entry-stream shape and
+    `repro.sim.events_batched` engine, packed by entry-stream length and
     vmapped, so a whole Table-9-style grid costs a handful of dispatches
     instead of one serial `events.simulate_events` loop per cell.
 

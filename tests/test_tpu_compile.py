@@ -117,13 +117,15 @@ def _event_plan(n_cells=32):
     from repro.sim.events import DISPATCHERS
     from repro.sim.plan import plan_events
     from repro.sim.sweep import EventCell
+    # two stream lengths merged into one chunk at the longer one's E
     rng = np.random.default_rng(0)
     cells = [EventCell(DISPATCHERS[i % 3],
-                       np.sort(rng.uniform(0.0, 300.0, 600)), 0.25,
-                       DEFAULT_FLEET, horizon_s=300.0)
+                       np.sort(rng.uniform(0.0, 300.0, (600, 6000)[i % 2])),
+                       0.25, DEFAULT_FLEET, horizon_s=300.0)
              for i in range(n_cells)]
     plan = plan_events(cells, n_max=128)
     assert len(plan.dispatches) == 1
+    assert plan.meta["cells_widened"] == n_cells // 2
     return plan.dispatches[0]
 
 
@@ -173,10 +175,10 @@ def _pool_population(blocks: int, n_tenants: int = 8192,
 
 def test_fleet_pool_chunk_compiles_for_v5e(one_chip):
     """The benchmark's FaaS pool cell as it is planned: two populations
-    of 8,192 tenants over 1200 s, each population's six cells (three
-    dispatchers x two admissions) one 8-lane dispatch, at the cell's
-    entry counts (E 4,864 and 5,120), on a W = 192 + 320 worker table
-    with ``n_max=256``; the longer dispatch is compiled."""
+    of 8,192 tenants over 1200 s, their twelve cells (three dispatchers
+    x two admissions each) one 16-lane dispatch at the longer entry
+    count (E 5,120; the other population's is 4,864), on a W = 192 +
+    320 worker table with ``n_max=256``."""
     from repro.core.workers import DEFAULT_FLEET
     from repro.fleet import FleetCell
     from repro.fleet import engine as fleet_engine
@@ -192,8 +194,9 @@ def test_fleet_pool_chunk_compiles_for_v5e(one_chip):
     plan = plan_fleet(cells, n_max=256, w_fpga=192, w_cpu=320)
     shapes = sorted((d.chunk, d.n_real, *d.arrays["times"].shape[1:],
                      d.arrays["ta_size"].shape[1]) for d in plan.dispatches)
-    assert shapes == [(8, 6, 4864, 128, 8192), (8, 6, 5120, 128, 8192)]
-    d = max(plan.dispatches, key=lambda d: d.arrays["times"].shape[1])
+    assert shapes == [(16, 12, 5120, 128, 8192)]
+    assert plan.meta["cells_widened"] == 6
+    (d,) = plan.dispatches
     assert d.static[:3] == (256, 192, 320)
     args = _specs_like(_fleet_args(d), one_chip)
     compiled = fleet_engine._simulate_fleet_cells.lower(*d.static, *args) \

@@ -18,7 +18,9 @@ import pytest
 from repro.core.traces import synthetic_trace
 from repro.core.workers import DEFAULT_FLEET
 from repro.fleet import FleetCell, TenantSpec
-from repro.sim.plan import plan_events, plan_fleet, plan_sweep
+from repro.ft.failures import FailureSpec
+from repro.sim.plan import (_round_entries, plan_events, plan_fleet,
+                            plan_sweep)
 from repro.sim.sweep import EventCell, SweepCell, sweep_events
 
 Span = namedtuple("Span", "name start end stats line")
@@ -32,12 +34,16 @@ SMALL = dict(n_max=64, w_fpga=16, w_cpu=32)
 
 
 def event_cells(horizon=60.0):
-    # two stream lengths, so two entry widths E and two dispatches
+    # two stream lengths share one dispatch; a failure-enabled cell is
+    # another static key, so a second dispatch
     rng = np.random.default_rng(5)
     streams = [np.sort(rng.uniform(0.0, horizon, n)) for n in (40, 1500)]
     return [EventCell(d, t, 0.05, DEFAULT_FLEET, horizon_s=horizon)
             for t in streams
-            for d in ("spork", "round_robin", "index_packing")]
+            for d in ("spork", "round_robin", "index_packing")] + [
+        EventCell("spork", streams[1], 0.05, DEFAULT_FLEET,
+                  horizon_s=horizon,
+                  failures=FailureSpec(crash_p=0.03, seed=5))]
 
 
 def fleet_cells():
@@ -169,7 +175,8 @@ def test_result_meta_carries_the_plan_counters(grid):
     plan = plan_events(cells, **SMALL)
     want = {k: v for k, v in plan.meta.items() if k != "plan_id"}
     assert set(want) == {"cells", "rows", "h2d_bytes", "row_entries",
-                         "entries_scanned", "entries", "arrivals"}
+                         "entries_scanned", "entries", "arrivals",
+                         "serial_entries", "cells_widened"}
     assert {k: res.meta[k] for k in want} == want
     assert res.meta["executed_chunks"] == res.n_dispatches
 
@@ -222,7 +229,11 @@ def direct(plan) -> dict:
                             * d.arrays["times"].shape[1] for d in ds),
         entries=sum(_entries_of_row(t[r], k[r])
                     for t, k in real for r in range(len(t))),
-        arrivals=sum(int(np.isfinite(t).sum()) for t, _ in real))
+        arrivals=sum(int(np.isfinite(t).sum()) for t, _ in real),
+        serial_entries=sum(d.arrays["times"].shape[1] for d in ds),
+        cells_widened=sum(_round_entries(_entries_of_row(t[r], k[r]))
+                          < t.shape[1]
+                          for t, k in real for r in range(len(t))))
     return out
 
 
@@ -230,7 +241,8 @@ PLANS = {"event": lambda: plan_events(event_cells(), **SMALL),
          "fleet": lambda: plan_fleet(fleet_cells(), **SMALL),
          "rate": lambda: plan_sweep(rate_cells())}
 ENTRY_COUNTERS = ("cells", "rows", "row_entries", "entries_scanned",
-                  "entries", "arrivals", "h2d_bytes")
+                  "entries", "arrivals", "h2d_bytes", "serial_entries",
+                  "cells_widened")
 TENANT_COUNTERS = ("tenants", "tenant_slots", "populations")
 
 
