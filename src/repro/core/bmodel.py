@@ -18,7 +18,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.rng import pinned_stream
 
+
+@pinned_stream
 @functools.partial(jax.jit, static_argnames=("levels",))
 def bmodel_series(key: jax.Array, bias: jax.Array | float, levels: int,
                   total_volume: jax.Array | float) -> jax.Array:

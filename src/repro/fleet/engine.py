@@ -1,8 +1,9 @@
 """Batched multi-tenant fleet engine: tenant axis in the DES scan state.
 
-The batched twin of `repro.fleet.oracle.FleetSim`, built ON TOP of the
-single-tenant batched DES (`repro.sim.events_batched`) rather than
-beside it: each arrival entry carries a tenant index, and the inner scan
+The batched twin of `repro.fleet.oracle.FleetSim`: the single-tenant
+DES cell program (`repro.sim.events_batched._simulate_cell`: table and
+carry set-up, entry scan, ticks, final drain, `Accum`) plus a tenant
+step. Each arrival entry carries a tenant index, and `_fleet_arrival`
 
   1. gathers the tenant's admission state, runs the shared float32
      `repro.policies.admission.admission_decide` kernel under the traced
@@ -19,12 +20,14 @@ beside it: each arrival entry carries a tenant index, and the inner scan
      delta-observation trick the serial oracle uses, so the two engines
      cannot disagree on attribution rules.
 
-Interval ticks run the unchanged `_tick_step` on *aggregate* interval
-load (the allocator never reads size/deadline) and reset the
-`interval_quota` admission counters. The cell axis is vmapped exactly
-like the single-tenant engine; `repro.sim.plan.plan_fleet` builds the
-dispatches and both `repro.sim.exec` backends run them (`MeshBackend`
-shard_maps `_simulate_fleet_cells_core` over the cell mesh).
+The admission state and `FleetTenantAcc` ride as the cell program's
+extra state. Interval ticks run the unchanged `_tick_step` on
+*aggregate* interval load (the allocator never reads size/deadline) and
+reset the `interval_quota` admission counters (`_quota_reset`). The
+cell axis is vmapped exactly like the single-tenant engine;
+`repro.sim.plan.plan_fleet` builds the dispatches and both
+`repro.sim.exec` backends run them (`MeshBackend` shard_maps
+`_simulate_fleet_cells_core` over the cell mesh).
 
 Equivalence contract: on dyadic-quantized tenant streams the engine
 matches `FleetSim` EXACTLY on offered/admitted/shed/missed counters and
@@ -41,11 +44,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.policies import admission_decide
-from repro.sim.events_batched import (EvCarry, EventScalars, TickState,
-                                      WorkerTable, _arrival_fail,
-                                      _arrival_step, _fail_zero, _settle,
-                                      _tick_step)
-from repro.sim.ratesim import Accum
+from repro.sim.events_batched import (EventScalars, _arrival_fail,
+                                      _arrival_step, _simulate_cell)
 
 
 class FleetTenantAcc(NamedTuple):
@@ -59,11 +59,11 @@ class FleetTenantAcc(NamedTuple):
     work_c: jnp.ndarray      # f32 cpu-seconds served on CPUs
 
 
-def _fleet_arrival(es: EventScalars, fstat, code, acode, w_f: int, is_f,
-                   idxW, ta_size, ta_dl, adm_rate, adm_burst, adm_quota,
-                   carry, xs):
+def _fleet_arrival(es: EventScalars, fstat, code, acode, w_f: int,
+                   ta_size, ta_dl, adm_rate, adm_burst, adm_quota, is_f,
+                   idxW, carry, xs):
     """One tenant-tagged arrival: admission -> (gated) dispatch -> tally."""
-    c, tok, last, cnt, fa = carry
+    c, (tok, last, cnt, fa) = carry
     t, tid = xs
     real = jnp.isfinite(t)
     # padded entries (t = +inf) must not poison the float32 admission
@@ -102,77 +102,33 @@ def _fleet_arrival(es: EventScalars, fstat, code, acode, w_f: int, is_f,
             jnp.where(served_f, ta_size[tid], 0.0)),
         work_c=fa.work_c.at[tid].add(
             jnp.where(served_c, ta_size[tid], 0.0)))
-    return (c2, tok, last, cnt, fa), None
+    return c2, (tok, last, cnt, fa)
+
+
+def _quota_reset(extra, tick):
+    """Interval quota counters restart at every tick."""
+    tok, last, cnt, fa = extra
+    return tok, last, jnp.where(tick, jnp.zeros_like(cnt), cnt), fa
 
 
 def _simulate_fleet_one(n_max: int, w_f: int, w_c: int, fstat, es, code,
                         acode, times, tids, tick_t, is_tick, ta_size,
                         ta_dl, adm_rate, adm_burst, adm_quota) -> tuple:
-    """One fleet cell over the flat tenant-tagged entry stream. Mirrors
-    `repro.sim.events_batched._simulate_one` (same worker-table init,
-    same entry scan, same final drain + `Accum` derivation) with the
-    admission state + `FleetTenantAcc` threaded alongside; interval
-    quota counters reset on tick entries."""
-    W = w_f + w_c
-    is_f = jnp.arange(W) < w_f
-    idxW = jnp.arange(W, dtype=jnp.float32)
+    """One fleet cell: `repro.sim.events_batched._simulate_cell` over
+    the flat tenant-tagged entry stream, stepping each arrival with
+    `_fleet_arrival`, with the admission state + `FleetTenantAcc` as its
+    extra state."""
     n_ten = ta_size.shape[0]
-
-    def zf(*s):
-        return jnp.zeros(s, jnp.float32)
-
-    ws = WorkerTable(wid=jnp.zeros((W,), jnp.int32),
-                     alive=jnp.zeros((W,), bool), alloc_t=zf(W),
-                     ready_at=zf(W), avail=zf(W), busy=zf(W),
-                     level=jnp.zeros((W,), jnp.int32),
-                     n_assign=jnp.zeros((W,), jnp.int32),
-                     crash_t=jnp.full((W,), jnp.inf, jnp.float32),
-                     slow=jnp.ones((W,), jnp.float32),
-                     nfail=jnp.zeros((W,), jnp.int32))
-    c0 = EvCarry(ws, zf(W), zf(W), jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                 _fail_zero())
-    ts0 = TickState(H=zf(n_max, n_max), n_lag=jnp.zeros((2,), jnp.int32),
-                    life_sum=zf(n_max), life_cnt=zf(n_max), F_prev=zf(),
-                    C_prev=zf(), spins=zf(), energy=zf(6))
     zi = jnp.zeros((n_ten,), jnp.int32)
-    fa0 = FleetTenantAcc(zi, zi, zi, zi, zf(n_ten), zf(n_ten))
-    tok0, last0, cnt0 = adm_burst, zf(n_ten), zi
-
+    fa0 = FleetTenantAcc(zi, zi, zi, zi, jnp.zeros((n_ten,), jnp.float32),
+                         jnp.zeros((n_ten,), jnp.float32))
     step = functools.partial(_fleet_arrival, es, fstat, code, acode, w_f,
-                             is_f, idxW, ta_size, ta_dl, adm_rate,
-                             adm_burst, adm_quota)
-
-    def entry(state, xs):
-        c, ts, tok, last, cnt, fa = state
-        row_t, row_id, tt, tk = xs
-        (c, tok, last, cnt, fa), _ = jax.lax.scan(
-            step, (c, tok, last, cnt, fa), (row_t, row_id))
-        c, ts = _tick_step(es, fstat, w_f, is_f, c, ts, tt, tk)
-        cnt = jnp.where(tk, jnp.zeros_like(cnt), cnt)
-        return (c, ts, tok, last, cnt, fa), None
-
-    (c, ts, _, _, _, fa), _ = jax.lax.scan(
-        entry, (c0, ts0, tok0, last0, cnt0, fa0),
-        (times, tids, tick_t, is_tick))
-    c, ts = _settle(es, is_f, c, ts, jnp.inf, True)
-    fl = c.fail
-    if fstat.enabled:
-        work_f, work_c = fl.work_f, fl.work_c
-        missed = jnp.sum(c.miss_slot) + fl.dropped.astype(jnp.float32)
-        cpu_spins = fl.cpu_spins.astype(jnp.float32)
-    else:
-        work_f = jnp.sum(c.serv_slot[:w_f]) * es.S
-        work_c = jnp.sum(c.serv_slot[w_f:])
-        missed = jnp.sum(c.miss_slot)
-        cpu_spins = c.next_wid.astype(jnp.float32) - ts.spins
-    acc = Accum(
-        fpga_busy_j=ts.energy[0], fpga_idle_j=ts.energy[1],
-        cpu_busy_j=ts.energy[2], cpu_idle_j=ts.energy[3],
-        spin_j=ts.energy[4], cost=ts.energy[5],
-        work_f=work_f, work_c=work_c,
-        missed_requests=missed, fpga_spinups=ts.spins,
-        cpu_spinups=cpu_spins)
-    return acc, fl, c.overflow, fa
+                             ta_size, ta_dl, adm_rate, adm_burst, adm_quota)
+    acc, fl, over, (_, _, _, fa) = _simulate_cell(
+        n_max, w_f, w_c, fstat, es, step,
+        (adm_burst, jnp.zeros((n_ten,), jnp.float32), zi, fa0),
+        _quota_reset, (times, tids), tick_t, is_tick)
+    return acc, fl, over, fa
 
 
 def _simulate_fleet_cells_core(n_max: int, w_fpga: int, w_cpu: int,

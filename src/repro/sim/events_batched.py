@@ -639,12 +639,20 @@ def _tick_step(es: EventScalars, fstat: FailStatic, w_f: int, is_f,
         spins=ts.spins + n_spun.astype(jnp.float32))
     return c, ts
 
-def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
-                  es: EventScalars, code, times, tick_t, is_tick) -> tuple:
-    """One cell over the flat entry stream: each entry runs one (padded)
-    arrival block through the inner scan, then one gated tick. ``fstat``
-    selects the compiled program: disabled cells run the pristine
-    pre-failure path (bit-identical to the engine without the axis)."""
+def _simulate_cell(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
+                   es: EventScalars, arrival, extra, tick_reset, rows,
+                   tick_t, is_tick) -> tuple:
+    """One DES cell program over the flat entry stream: each entry runs
+    one (padded) arrival block through the inner scan, then one gated
+    tick; the final drain settles every worker and the totals become an
+    `Accum`. ``arrival(is_f, idxW, (c, extra), xs)`` steps one arrival,
+    ``xs`` holding one slot of each array of ``rows`` (``E x BLOCK``);
+    ``extra`` is state carried beside the `EvCarry` (the fleet layer's
+    tenant axis), passed through ``tick_reset(extra, tick)`` after every
+    entry's tick. Returns ``(Accum, FailAcc, overflow, extra)``.
+    ``fstat`` selects the compiled program: disabled cells run the
+    pristine pre-failure path (bit-identical to the engine without the
+    axis)."""
     W = w_f + w_c
     is_f = jnp.arange(W) < w_f
     idxW = jnp.arange(W, dtype=jnp.float32)
@@ -666,20 +674,18 @@ def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
                     life_sum=zf(n_max), life_cnt=zf(n_max), F_prev=zf(),
                     C_prev=zf(), spins=zf(), energy=zf(6))
 
+    def inner(carry, xs):
+        return arrival(is_f, idxW, carry, xs), None
+
     def entry(state, xs):
-        c, ts = state
+        c, ts, ex = state
         row, tt, tk = xs
+        (c, ex), _ = jax.lax.scan(inner, (c, ex), row)
+        c, ts = _tick_step(es, fstat, w_f, is_f, c, ts, tt, tk)
+        return (c, ts, tick_reset(ex, tk)), None
 
-        def inner(cc, ta):
-            if fstat.enabled:
-                return _arrival_fail(es, fstat, code, w_f, is_f, idxW,
-                                     cc, ta), None
-            return _arrival_step(es, code, w_f, is_f, idxW, cc, ta), None
-
-        c, _ = jax.lax.scan(inner, c, row)
-        return _tick_step(es, fstat, w_f, is_f, c, ts, tt, tk), None
-
-    (c, ts), _ = jax.lax.scan(entry, (c0, ts0), (times, tick_t, is_tick))
+    (c, ts, ex), _ = jax.lax.scan(entry, (c0, ts0, extra),
+                                  (rows, tick_t, is_tick))
     # final drain: every remaining worker idles out at its own timeout
     c, ts = _settle(es, is_f, c, ts, jnp.inf, True)
     fl = c.fail
@@ -702,7 +708,23 @@ def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
         work_f=work_f, work_c=work_c,
         missed_requests=missed, fpga_spinups=ts.spins,
         cpu_spinups=cpu_spins)
-    return acc, fl, c.overflow
+    return acc, fl, c.overflow, ex
+
+
+def _simulate_one(n_max: int, w_f: int, w_c: int, fstat: FailStatic,
+                  es: EventScalars, code, times, tick_t, is_tick) -> tuple:
+    """One single-tenant cell: `_simulate_cell` stepping each arrival
+    with `_arrival_step` (or `_arrival_fail`), no extra state."""
+    def arrival(is_f, idxW, carry, t):
+        c, ex = carry
+        if fstat.enabled:
+            return _arrival_fail(es, fstat, code, w_f, is_f, idxW, c, t), ex
+        return _arrival_step(es, code, w_f, is_f, idxW, c, t), ex
+
+    acc, fl, over, _ = _simulate_cell(n_max, w_f, w_c, fstat, es, arrival,
+                                      (), lambda ex, tk: ex, times, tick_t,
+                                      is_tick)
+    return acc, fl, over
 
 
 def _simulate_cells_core(n_max: int, w_fpga: int, w_cpu: int,
@@ -721,14 +743,15 @@ _simulate_cells = functools.partial(
     _simulate_cells_core)
 
 
-def _scalars(cell: "EventCell") -> tuple:
-    fleet = cell.fleet
-    tb, coeffs = objective_setup(fleet, cell.energy_weight)
-    deadline = (10.0 * cell.size_s if cell.deadline_s is None
-                else cell.deadline_s)
-    f = cell.failures.normalized() if cell.failures is not None else None
+def _scalars(fleet: FleetParams, energy_weight: float, size_s: float,
+             deadline_s: float | None, failures: FailureSpec | None) -> tuple:
+    """A cell's float `EventScalars` fields, in order, from the DES axes
+    they read (``deadline_s`` None: 10 x the request size)."""
+    tb, coeffs = objective_setup(fleet, energy_weight)
+    deadline = 10.0 * size_s if deadline_s is None else deadline_s
+    f = failures.normalized() if failures is not None else None
     ff = f.floats() if f is not None else (0.0,) * 8
-    return (cell.size_s, deadline, fleet.S, fleet.T_s, tb, coeffs.co_min,
+    return (size_s, deadline, fleet.S, fleet.T_s, tb, coeffs.co_min,
             coeffs.co_over, coeffs.co_under, coeffs.amort_unit,
             fleet.fpga.spin_up_s, fleet.cpu.spin_up_s,
             fleet.fpga_idle_timeout_s, fleet.cpu_idle_timeout_s,
@@ -736,9 +759,7 @@ def _scalars(cell: "EventCell") -> tuple:
             fleet.cpu.idle_w, fleet.fpga.cost_per_s, fleet.cpu.cost_per_s,
             fleet.fpga.spin_up_energy_j + fleet.fpga.spin_down_energy_j,
             fleet.cpu.spin_up_energy_j + fleet.cpu.spin_down_energy_j,
-            fleet.fpga.spin_down_s, fleet.cpu.spin_down_s,
-            *ff,
-            fleet.max_fpgas, cell.allocate_fpgas)
+            fleet.fpga.spin_down_s, fleet.cpu.spin_down_s, *ff)
 
 
 @dataclass(frozen=True)

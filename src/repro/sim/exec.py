@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
+from repro.core.metrics import RunTotals
 from repro.policies import RateParams
 from repro.sim import events_batched, ratesim
 from repro.sim.plan import (Accum, ChunkDispatch, EventSweepResult,
@@ -76,20 +77,24 @@ def _rate_args(d: ChunkDispatch) -> tuple:
             jnp.asarray(a["energy_weight"]), params)
 
 
-def _event_args(d: ChunkDispatch) -> tuple:
-    """Traced arguments for `events_batched._simulate_cells`, in order.
-    The ``scalars`` matrix holds every float field of `EventScalars`
-    (incl. the 8 traced failure knobs); the uint32 hash seed and the
-    int/bool fields ride as separate arrays."""
-    a = d.arrays
-    es = events_batched.EventScalars(
+def _event_scalars(a: dict) -> events_batched.EventScalars:
+    """A DES dispatch's `EventScalars`: the ``scalars`` matrix holds
+    every float field (incl. the 8 traced failure knobs); the uint32
+    hash seed and the int/bool fields ride as separate arrays."""
+    return events_batched.EventScalars(
         *(jnp.asarray(a["scalars"][:, j])
           for j in range(a["scalars"].shape[1])),
         f_seed=jnp.asarray(a["fail_seed"]),
         max_fpgas=jnp.asarray(a["max_fpgas"]),
         allocate=jnp.asarray(a["allocate"]))
-    return (es, jnp.asarray(a["codes"]), jnp.asarray(a["times"]),
-            jnp.asarray(a["tick_t"]), jnp.asarray(a["is_tick"]))
+
+
+def _event_args(d: ChunkDispatch) -> tuple:
+    """Traced arguments for `events_batched._simulate_cells`, in order."""
+    a = d.arrays
+    return (_event_scalars(a), jnp.asarray(a["codes"]),
+            jnp.asarray(a["times"]), jnp.asarray(a["tick_t"]),
+            jnp.asarray(a["is_tick"]))
 
 
 def _fleet_args(d: ChunkDispatch) -> tuple:
@@ -98,13 +103,8 @@ def _fleet_args(d: ChunkDispatch) -> tuple:
     tenant indices and the padded per-tenant size/deadline/admission
     tables."""
     a = d.arrays
-    es = events_batched.EventScalars(
-        *(jnp.asarray(a["scalars"][:, j])
-          for j in range(a["scalars"].shape[1])),
-        f_seed=jnp.asarray(a["fail_seed"]),
-        max_fpgas=jnp.asarray(a["max_fpgas"]),
-        allocate=jnp.asarray(a["allocate"]))
-    return (es, jnp.asarray(a["codes"]), jnp.asarray(a["acodes"]),
+    return (_event_scalars(a), jnp.asarray(a["codes"]),
+            jnp.asarray(a["acodes"]),
             jnp.asarray(a["times"]), jnp.asarray(a["tids"]),
             jnp.asarray(a["tick_t"]), jnp.asarray(a["is_tick"]),
             jnp.asarray(a["ta_size"]), jnp.asarray(a["ta_deadline"]),
@@ -292,36 +292,40 @@ def _execute_rate(plan: SweepPlan, backend: Backend, runner) -> SweepResult:
                        n_devices=backend.n_devices, dispatch_devices=devs)
 
 
+def _des_totals(acc, fail, over, r: int, work: float,
+                n_req: int) -> RunTotals:
+    """Row r of a DES dispatch's (Accum, FailAcc, overflow) host arrays
+    as `RunTotals` of ``n_req`` requests and ``work`` cpu-seconds."""
+    tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc]), work, n_req)
+    fl = events_batched.FailAcc(*[leaf[r] for leaf in fail])
+    # resilience counters + the oracle's finalize composition: wasted
+    # spin-up energy joins energy_j, stillborn occupancy joins cost_usd
+    # (all exactly zero when the axis is off)
+    tot.retries = int(fl.retries)
+    tot.failed_spinups = int(fl.failed_spins)
+    tot.crashes = int(fl.crashes)
+    tot.recovered_requests = int(fl.recovered)
+    tot.failure_misses = int(fl.fail_misses)
+    tot.wasted_spinup_j = float(fl.wasted_j)
+    tot.energy_j += float(fl.wasted_j)
+    tot.cost_usd += float(fl.extra_cost)
+    tot.breakdown["slot_overflow"] = int(over[r])
+    return tot
+
+
 def _execute_event(plan: SweepPlan, backend: Backend,
                    runner) -> EventSweepResult:
     out = [None] * len(plan.cells)
     devs = []
     for d in plan.dispatches:
-        acc, fail, over = runner.run(d)
+        acc, fail, over = runner.run(d)      # host arrays
         devs.append(backend.devices_for(d))
         with TraceAnnotation("repro.exec.scatter"):
-            acc_np = [np.asarray(leaf) for leaf in acc]
-            fail_np = [np.asarray(leaf) for leaf in fail]
-            over_np = np.asarray(over)
             for r, i in enumerate(d.cell_idx):
                 cell = plan.cells[i]
                 n_req = len(cell.arrival_times)
-                tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc_np]),
-                                      n_req * cell.size_s, n_req)
-                fl = events_batched.FailAcc(*[leaf[r] for leaf in fail_np])
-                # resilience counters + the oracle's finalize composition:
-                # wasted spin-up energy joins energy_j, stillborn occupancy
-                # joins cost_usd (all exactly zero when the axis is off)
-                tot.retries = int(fl.retries)
-                tot.failed_spinups = int(fl.failed_spins)
-                tot.crashes = int(fl.crashes)
-                tot.recovered_requests = int(fl.recovered)
-                tot.failure_misses = int(fl.fail_misses)
-                tot.wasted_spinup_j = float(fl.wasted_j)
-                tot.energy_j += float(fl.wasted_j)
-                tot.cost_usd += float(fl.extra_cost)
-                tot.breakdown["slot_overflow"] = int(over_np[r])
-                out[i] = tot
+                out[i] = _des_totals(acc, fail, over, r,
+                                     n_req * cell.size_s, n_req)
     return EventSweepResult(plan.cells, out, n_dispatches=plan.n_dispatches,
                             backend=backend.name,
                             n_devices=backend.n_devices,
@@ -345,32 +349,18 @@ def _execute_fleet(plan: SweepPlan, backend: Backend,
     tenants = [None] * len(plan.cells)
     devs = []
     for d in plan.dispatches:
-        acc, fail, over, fa = runner.run(d)
+        acc, fail, over, fa = runner.run(d)  # host arrays
         devs.append(backend.devices_for(d))
         with TraceAnnotation("repro.exec.scatter"):
-            acc_np = [np.asarray(leaf) for leaf in acc]
-            fail_np = [np.asarray(leaf) for leaf in fail]
-            over_np = np.asarray(over)
-            fa_np = [np.asarray(leaf) for leaf in fa]
             for r, i in enumerate(d.cell_idx):
                 cell = plan.cells[i]
                 rs = resolve_fleet_cell(cell)       # lru-cached
                 n = rs.n_tenants
                 offered, admitted, shed, missed, work_f, work_c = (
-                    leaf[r, :n] for leaf in fa_np)
-                n_adm = int(admitted.sum())
+                    leaf[r, :n] for leaf in fa)
                 work = float((admitted.astype(np.float64) * rs.sizes).sum())
-                tot = accum_to_totals(Accum(*[leaf[r] for leaf in acc_np]),
-                                      work, n_adm)
-                fl = events_batched.FailAcc(*[leaf[r] for leaf in fail_np])
-                tot.retries = int(fl.retries)
-                tot.failed_spinups = int(fl.failed_spins)
-                tot.crashes = int(fl.crashes)
-                tot.recovered_requests = int(fl.recovered)
-                tot.failure_misses = int(fl.fail_misses)
-                tot.wasted_spinup_j = float(fl.wasted_j)
-                tot.energy_j += float(fl.wasted_j)
-                tot.cost_usd += float(fl.extra_cost)
+                tot = _des_totals(acc, fail, over, r, work,
+                                  int(admitted.sum()))
                 # per-tenant sums ARE the fleet-level numbers (each arrival
                 # increments exactly one tenant's counter and the matching
                 # shared counter, so these agree with the Accum up to f32)
@@ -379,7 +369,6 @@ def _execute_fleet(plan: SweepPlan, backend: Backend,
                     work_f.astype(np.float64).sum())
                 tot.work_on_cpu_cpu_s = float(
                     work_c.astype(np.float64).sum())
-                tot.breakdown["slot_overflow"] = int(over_np[r])
                 tot.breakdown["offered_requests"] = int(offered.sum())
                 tot.breakdown["shed_requests"] = int(shed.sum())
                 out[i] = tot

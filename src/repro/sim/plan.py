@@ -263,6 +263,48 @@ def _pack_by_length(groups: dict, entries: dict[int, list]) -> list[tuple]:
     return out
 
 
+def _des_arrays(cells: list, pad: list[int], E: int,
+                entries: dict[int, list], codes: dict[int, int],
+                requests: dict[int, tuple]) -> dict[str, np.ndarray]:
+    """The arrays of a DES dispatch that event and fleet plans share,
+    for its padded rows ``pad`` (cell indices): the entry streams padded
+    to E entries, ``times`` / ``tick_t`` / ``is_tick`` (and ``tids``
+    when the streams carry a payload), and the per-cell columns
+    ``scalars`` (the float `EventScalars` fields), ``fail_seed``,
+    ``max_fpgas``, ``allocate`` and ``codes``. ``requests`` maps a cell
+    to its (size_s, deadline_s, failures); its fleet, energy weight and
+    allocator switch are read from the cell."""
+    times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
+    tick_t = np.zeros((len(pad), E), np.float32)
+    is_tick = np.zeros((len(pad), E), bool)
+    payload = len(entries[pad[0]][0]) == 3
+    tids = np.zeros((len(pad), E, BLOCK), np.int32) if payload else None
+    for r, i in enumerate(pad):
+        for e, ent in enumerate(entries[i]):
+            row, tick = ent[0], ent[-1]
+            times[r, e, :len(row)] = row
+            if payload:
+                tids[r, e, :len(row)] = ent[1]
+            if tick is not None:
+                tick_t[r, e] = tick
+                is_tick[r, e] = True
+    arrays = {
+        "scalars": np.array([_scalars(cells[i].fleet, cells[i].energy_weight,
+                                      *requests[i]) for i in pad],
+                            np.float32),
+        "fail_seed": np.array([(requests[i][2].seed
+                                if requests[i][2] is not None else 0)
+                               for i in pad], np.uint32),
+        "max_fpgas": np.array([cells[i].fleet.max_fpgas for i in pad],
+                              np.int32),
+        "allocate": np.array([cells[i].allocate_fpgas for i in pad], bool),
+        "codes": np.array([codes[i] for i in pad], np.int32),
+        "times": times, "tick_t": tick_t, "is_tick": is_tick}
+    if payload:
+        arrays["tids"] = tids
+    return arrays
+
+
 def _traced_plan(plan_fn):
     """Run a planner inside the ``repro.plan`` span and number its plan
     (``meta["plan_id"]``)."""
@@ -417,34 +459,14 @@ def plan_events(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
             groups.setdefault(fail_static(cl.failures), []).append(i)
 
     with TraceAnnotation("repro.plan.pack"):
+        requests = {i: (cl.size_s, cl.deadline_s, cl.failures)
+                    for i, cl in enumerate(cells)}
         dispatches: list[ChunkDispatch] = []
         for fstat, E, sl, pad in _pack_by_length(groups, entries):
-            times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
-            tick_t = np.zeros((len(pad), E), np.float32)
-            is_tick = np.zeros((len(pad), E), bool)
-            for r, i in enumerate(pad):
-                for e, (row, tick) in enumerate(entries[i]):
-                    times[r, e, :len(row)] = row
-                    if tick is not None:
-                        tick_t[r, e] = tick
-                        is_tick[r, e] = True
-            arrays = {
-                "scalars": np.array([_scalars(cells[i])[:-2] for i in pad],
-                                    np.float32),
-                "fail_seed": np.array(
-                    [(cells[i].failures.seed
-                      if cells[i].failures is not None else 0)
-                     for i in pad], np.uint32),
-                "max_fpgas": np.array([cells[i].fleet.max_fpgas
-                                       for i in pad], np.int32),
-                "allocate": np.array([cells[i].allocate_fpgas
-                                      for i in pad], bool),
-                "codes": np.array([codes[i] for i in pad], np.int32),
-                "times": times, "tick_t": tick_t, "is_tick": is_tick,
-            }
             dispatches.append(ChunkDispatch(
                 kind="event", static=(n_max, w_fpga, w_cpu, fstat),
-                arrays=arrays, cell_idx=tuple(sl), chunk=len(pad)))
+                arrays=_des_arrays(cells, pad, E, entries, codes, requests),
+                cell_idx=tuple(sl), chunk=len(pad)))
 
     return SweepPlan("event", cells, dispatches, n_max,
                      meta=_counters(dispatches, entries, arrivals))
@@ -470,9 +492,8 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
     `repro.fleet.engine` on either backend; `repro.sim.sweep.sweep_fleet`
     is the plan+execute wrapper returning a `FleetSweepResult`."""
     from repro.fleet.specs import FleetCell, resolve_fleet_cell
-    from repro.sim.events_batched import EventCell
-
     from repro.policies import get_admission_policy
+
     resolved: dict[int, Any] = {}
     with TraceAnnotation("repro.plan.resolve"):
         for i, cl in enumerate(cells):
@@ -502,18 +523,6 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
             groups.setdefault((N_pad, fail_static(rs.failures)),
                               []).append(i)
 
-    def _proxy(i: int) -> EventCell:
-        # an EventCell twin carrying the cell's fleet/objective axes so
-        # `_scalars` stays the single source of truth; size/deadline are
-        # tenant 0's (overridden per arrival by the tenant tables)
-        cl, rs = cells[i], resolved[i]
-        return EventCell(dispatcher=cl.dispatcher,
-                         size_s=float(rs.sizes[0]), fleet=cl.fleet,
-                         energy_weight=cl.energy_weight,
-                         deadline_s=float(rs.deadlines[0]),
-                         allocate_fpgas=cl.allocate_fpgas,
-                         failures=rs.failures)
-
     def _tenant_table(i: int, n_pad: int) -> np.ndarray:
         # (5, N_pad) f32 rows: size, deadline, adm_rate/burst/quota.
         # Padded tenant slots are never referenced by any tid; 1.0
@@ -530,39 +539,19 @@ def plan_fleet(cells: Iterable, n_max: int = 512, w_fpga: int = 32,
         return tbl
 
     with TraceAnnotation("repro.plan.pack"):
+        # size/deadline are tenant 0's (each arrival takes its tenant's
+        # from the tenant tables)
+        requests = {i: (float(rs.sizes[0]), float(rs.deadlines[0]),
+                        rs.failures) for i, rs in resolved.items()}
         dispatches: list[ChunkDispatch] = []
         for (N_pad, fstat), E, sl, pad in _pack_by_length(groups, entries):
-            times = np.full((len(pad), E, BLOCK), np.inf, np.float32)
-            tids = np.zeros((len(pad), E, BLOCK), np.int32)
-            tick_t = np.zeros((len(pad), E), np.float32)
-            is_tick = np.zeros((len(pad), E), bool)
-            for r, i in enumerate(pad):
-                for e, (row, prow, tick) in enumerate(entries[i]):
-                    times[r, e, :len(row)] = row
-                    tids[r, e, :len(prow)] = prow
-                    if tick is not None:
-                        tick_t[r, e] = tick
-                        is_tick[r, e] = True
             tables = np.stack([_tenant_table(i, N_pad) for i in pad])
-            arrays = {
-                "scalars": np.array(
-                    [_scalars(_proxy(i))[:-2] for i in pad], np.float32),
-                "fail_seed": np.array(
-                    [(resolved[i].failures.seed
-                      if resolved[i].failures is not None else 0)
-                     for i in pad], np.uint32),
-                "max_fpgas": np.array([cells[i].fleet.max_fpgas
-                                       for i in pad], np.int32),
-                "allocate": np.array([cells[i].allocate_fpgas
-                                      for i in pad], bool),
-                "codes": np.array([codes[i] for i in pad], np.int32),
-                "acodes": np.array([acodes[i] for i in pad], np.int32),
-                "times": times, "tids": tids,
-                "tick_t": tick_t, "is_tick": is_tick,
-                "ta_size": tables[:, 0], "ta_deadline": tables[:, 1],
-                "adm_rate": tables[:, 2], "adm_burst": tables[:, 3],
-                "adm_quota": tables[:, 4],
-            }
+            arrays = _des_arrays(cells, pad, E, entries, codes, requests)
+            arrays.update(
+                acodes=np.array([acodes[i] for i in pad], np.int32),
+                ta_size=tables[:, 0], ta_deadline=tables[:, 1],
+                adm_rate=tables[:, 2], adm_burst=tables[:, 3],
+                adm_quota=tables[:, 4])
             dispatches.append(ChunkDispatch(
                 kind="fleet", static=(n_max, w_fpga, w_cpu, fstat),
                 arrays=arrays, cell_idx=tuple(sl), chunk=len(pad)))

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bmodel import bmodel_series
+from repro.core.rng import pinned_stream
 
 
 def interp_minutes(per_min: jnp.ndarray, horizon_s: int) -> jnp.ndarray:
@@ -64,6 +65,7 @@ def bmodel_rates_jnp(key: jax.Array, bias, horizon_s: int,
     return interp_minutes(per_min, horizon_s)
 
 
+@pinned_stream
 def mmpp_rates(key: jax.Array, horizon_s: int, mean_rate,
                burst_ratio=8.0, p_enter=0.02, p_exit=0.2) -> jnp.ndarray:
     """2-state MMPP rates via `lax.scan` over seconds.
@@ -92,6 +94,7 @@ def mmpp_rates(key: jax.Array, horizon_s: int, mean_rate,
     return rates
 
 
+@pinned_stream
 def diurnal_rates(key: jax.Array, horizon_s: int, mean_rate,
                   period_s=86400.0, amp1=0.6, amp2=0.25, phase=0.0,
                   noise=0.08) -> jnp.ndarray:
@@ -109,6 +112,7 @@ def diurnal_rates(key: jax.Array, horizon_s: int, mean_rate,
     return jnp.float32(mean_rate) * rates / jnp.maximum(jnp.mean(rates), 1e-9)
 
 
+@pinned_stream
 def flash_crowd_overlay(key: jax.Array, horizon_s: int, amp=8.0,
                         ramp_s=30.0, decay_s=300.0,
                         window=(0.2, 0.7)) -> jnp.ndarray:
@@ -126,6 +130,7 @@ def flash_crowd_overlay(key: jax.Array, horizon_s: int, amp=8.0,
     return 1.0 + (jnp.float32(amp) - 1.0) * ramp * decay
 
 
+@pinned_stream
 def pareto_sizes(key: jax.Array, n: int, alpha=1.6, x_min_s=0.020,
                  cap_s=10.0) -> jnp.ndarray:
     """Pareto(alpha) request sizes with scale ``x_min_s``, capped at
@@ -135,6 +140,7 @@ def pareto_sizes(key: jax.Array, n: int, alpha=1.6, x_min_s=0.020,
                        jnp.float32(cap_s))
 
 
+@pinned_stream
 def lognormal_sizes(key: jax.Array, n: int, median_s=0.1, sigma=0.8,
                     lo_s=0.010, hi_s=10.0) -> jnp.ndarray:
     """Lognormal request sizes clipped to ``[lo_s, hi_s]`` (the demand
@@ -145,6 +151,7 @@ def lognormal_sizes(key: jax.Array, n: int, median_s=0.1, sigma=0.8,
                     jnp.float32(lo_s), jnp.float32(hi_s))
 
 
+@pinned_stream
 def poisson_counts(key: jax.Array, rates: jnp.ndarray) -> jnp.ndarray:
     """Poisson arrival counts for a rate grid — on-device twin of
     `Trace.sample_counts` (different RNG stream, same distribution)."""

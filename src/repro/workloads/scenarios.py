@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bmodel import bmodel_rates_np
+from repro.core.rng import pinned_stream
 from repro.workloads import generators, ingest
 
 BUCKETS_S = {
@@ -302,6 +303,7 @@ def _replay_base(spec: ScenarioSpec) -> tuple:
 
 
 @functools.lru_cache(maxsize=64)
+@pinned_stream
 def realize(spec: ScenarioSpec, seeds: tuple) -> ScenarioBatch:
     """Synthesize the whole seed batch for one spec in one dispatch.
 

@@ -137,6 +137,41 @@ def test_equivalence_property(cell):
     assert_fleet_match(cell)
 
 
+SEAM_ARRAYS = ("times", "tick_t", "is_tick", "scalars", "fail_seed",
+               "max_fpgas", "allocate", "codes")
+
+
+@pytest.mark.parametrize("dispatcher", ["round_robin", "index_packing",
+                                        "spork"])
+def test_one_tenant_fleet_cell_is_its_event_cell(dispatcher):
+    """A one-tenant `admit_all` fleet cell and the `EventCell` with its
+    stream, size, deadline, fleet and dispatcher share the DES pipeline:
+    their plans carry equal DES arrays and their dispatches give equal
+    `Accum`, `FailAcc` and overflow outputs, bit for bit."""
+    from repro.sim.exec import LocalBackend
+    from repro.sim.plan import plan_events
+    from repro.sim.sweep import EventCell
+    (tenant,) = dyadic_tenants(seed=9, n=1, n_arr=300)
+    fcell = FleetCell(tenants=(tenant,), dispatcher=dispatcher,
+                      fleet=QFLEET, horizon_s=60.0)
+    ecell = EventCell(dispatcher, np.asarray(tenant.arrival_times),
+                      tenant.request_size_s, QFLEET, horizon_s=60.0,
+                      deadline_s=10.0 * tenant.request_size_s)
+    kw = dict(n_max=64, w_fpga=16, w_cpu=32)
+    (fd,) = plan_fleet([fcell], **kw).dispatches
+    (ed,) = plan_events([ecell], **kw).dispatches
+    for k in SEAM_ARRAYS:
+        assert fd.arrays[k].dtype == ed.arrays[k].dtype, k
+        np.testing.assert_array_equal(fd.arrays[k], ed.arrays[k], err_msg=k)
+    acc_f, fail_f, over_f, _ = LocalBackend().run(fd)
+    acc_e, fail_e, over_e = LocalBackend().run(ed)
+    for name, a, b in ((("acc", acc_f, acc_e), ("fail", fail_f, fail_e))):
+        for f, x, y in zip(a._fields, a, b):
+            np.testing.assert_array_equal(x, y, err_msg=f"{name}.{f}")
+    np.testing.assert_array_equal(over_f, over_e)
+    assert float(acc_e.missed_requests[0]) >= 0 and float(acc_e.cost[0]) > 0
+
+
 # ---------------------------------------------------- admission + fairness
 
 def test_admission_sheds_and_conserves():

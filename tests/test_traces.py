@@ -9,6 +9,7 @@ the workloads-layer refactor (and any future one) stays bit-identical
 under fixed seeds.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -85,6 +86,16 @@ def test_golden_azure_like():
     assert int(az[0].counts.sum()) == 579336
     assert [int(x) for x in az[0].counts[:5]] == [3196, 3153, 3163, 3139, 3163]
     assert repr(az[1].request_size_s) == "0.08922030351678924"
+    assert int(az[1].counts.sum()) == 54686
+
+
+def test_golden_azure_like_under_partitionable_threefry():
+    """The stand-in streams keep their bits whatever the caller's
+    ``jax_threefry_partitionable`` (`repro.core.rng.pinned_stream`)."""
+    with jax.threefry_partitionable(True):
+        az = azure_like_apps("short", seed=1, horizon_s=600, n_apps=2)
+    assert repr(float(az[0].rates_per_s.sum())) == "578560.6386108398"
+    assert int(az[0].counts.sum()) == 579336
     assert int(az[1].counts.sum()) == 54686
 
 
